@@ -251,6 +251,8 @@ def run():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="reduced tier-2 campaign (CI smoke mode)")

@@ -172,6 +172,8 @@ def smoke_sharded_search(mesh) -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--devices", type=int, default=4,
                     help="expected jax.device_count()")

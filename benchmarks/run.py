@@ -6,6 +6,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (accuracy_bench, coexplore_bench,
                             coexplore_many_bench, dse_sweep_bench,
                             fig2_ppa_accuracy, fig3to5_dse, kernel_bench,

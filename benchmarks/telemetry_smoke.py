@@ -207,6 +207,8 @@ def overhead_gate(limit: float, reps: int, rounds: int
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", type=pathlib.Path,
                     default=pathlib.Path("/tmp/bench_telemetry_smoke.json"))

@@ -476,7 +476,6 @@ def get_jax_kernel(mesh=None, outputs: str = "full"):
     if mesh is None:
         fn = jax.jit(kernel)
     else:
-        from repro.launch.mesh import compat_shard_map
         P = jax.sharding.PartitionSpec
 
         def sharded(cfg, lay):
@@ -491,9 +490,10 @@ def get_jax_kernel(mesh=None, outputs: str = "full"):
                     if s.ndim >= 1 and s.shape[0] == n
                     else P(*([None] * s.ndim)))
                 for k, s in shapes.items()}
-            return compat_shard_map(
+            # replicated (1, L) layer stats are beyond the checker
+            return jax.shard_map(
                 kernel, mesh=mesh, in_specs=(cfg_specs, lay_specs),
-                out_specs=out_specs)(cfg, lay)
+                out_specs=out_specs, check_vma=False)(cfg, lay)
 
         fn = jax.jit(sharded)
     _JAX_KERNELS[key] = fn
@@ -931,7 +931,6 @@ def get_jax_many_kernel(bounds: tuple[tuple[int, int], ...], mesh=None):
         if mesh is None:
             fn = jax.jit(kernel)
         else:
-            from repro.launch.mesh import compat_shard_map
             P = jax.sharding.PartitionSpec
 
             def sharded(cfg, lay):
@@ -941,10 +940,10 @@ def get_jax_many_kernel(bounds: tuple[tuple[int, int], ...], mesh=None):
                 # aggregates — config-major on axis 1
                 out_specs = {k: P(None, "configs")
                              for k in AGGREGATE_OUTPUTS}
-                return compat_shard_map(
+                return jax.shard_map(
                     kernel, mesh=mesh,
                     in_specs=(cfg_specs, lay_specs),
-                    out_specs=out_specs)(cfg, lay)
+                    out_specs=out_specs, check_vma=False)(cfg, lay)
 
             fn = jax.jit(sharded)
         _JAX_MANY_KERNELS[key] = fn
@@ -1304,7 +1303,7 @@ def _sweep_chunked(workload: Workload,
                    checkpoint=None,
                    fail_at: dict[int, int] | None = None,
                    chunk_deadline_s: float | None = None,
-                   degrade_on_failure: bool = True) -> ChunkedSweep:
+                   degrade_on_failure: bool = False) -> ChunkedSweep:
     """Stream an arbitrary-size config feed through the sweep engine in
     bounded memory, keeping only running aggregates + the Pareto front.
 
@@ -1351,10 +1350,12 @@ def _sweep_chunked(workload: Workload,
     * ``chunk_deadline_s`` — watchdog: a dispatched chunk exceeding the
       deadline is cancelled and recomputed serially on the exact numpy
       kernel (counted in ``timings["watchdog_redispatches"]``).
-    * ``degrade_on_failure`` — a jax failure mid-stream (dispatch or
-      materialization) degrades the remaining stream to numpy with a
+    * ``degrade_on_failure`` — opt-in: a jax failure mid-stream (dispatch
+      or materialization) degrades the remaining stream to numpy with a
       warning instead of losing the run; stream order and cache
-      accounting are preserved (``timings["degraded"]``).
+      accounting are preserved (``timings["degraded"]``).  Off by
+      default, so a device failure raises instead of finishing on the
+      host.
     """
     import sys
     import time

@@ -375,17 +375,8 @@ class CompiledStats:
         }
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Version-compat: ``Compiled.cost_analysis()`` returns a dict on new
-    jax but a one-element list of dicts on jax <= 0.4.x."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze_compiled(compiled, hlo_text: str | None = None) -> CompiledStats:
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     if hlo_text is None:
         hlo_text = compiled.as_text()

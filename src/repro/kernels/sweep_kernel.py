@@ -16,7 +16,7 @@ scratch-accumulator idiom of :mod:`repro.kernels.w4a8_matmul`:
   (:func:`repro.core.dse_batch._sweep_kernel` with ``exact=False,
   outputs="layer_totals"``) on the tile's refs — one source of truth for
   the PPA math, so Pallas results track the jitted XLA path op-for-op;
-* a ``(W, block_l)`` segment mask gates the sequential Kahan update per
+* a ``(block_l, W)`` segment mask gates the sequential Kahan update per
   layer column, reproducing :func:`repro.core.dse_batch._kahan_sum_rows`
   over each ``[start, end)`` workload segment exactly (padded layer
   columns carry an all-zero mask and never touch the accumulators);
@@ -28,10 +28,10 @@ scratch-accumulator idiom of :mod:`repro.kernels.w4a8_matmul`:
 ``interpret=True`` (auto-selected when no accelerator platform is
 attached) runs the same kernel through the Pallas interpreter on CPU —
 bit-comparable to the jitted XLA path at the usual f32 tolerance, which
-CI asserts at ≤1e-6 relative against the exact numpy kernel.  On an
-accelerator the per-chunk config operands are donated
-(``donate_argnums``) so steady-state streaming stops double-buffering
-device memory.
+CI asserts at ≤1e-6 relative against the exact numpy kernel.  On a TPU
+the kernel is compiled by Mosaic, which needs every block's last two
+dimensions to be multiples of ``(8, 128)`` or the whole array's: the
+layer axis is one tile up to 128 layers and 128-wide tiles beyond.
 """
 
 from __future__ import annotations
@@ -57,6 +57,8 @@ CFG_FIELDS = ("pe_rows", "pe_cols", "num_pes", "act_bits", "weight_bits",
 LAY_FIELDS = ("r", "s", "e", "f", "c", "k", "h", "w", "batch", "macs")
 # the per-layer precision columns that may be (N, L) instead of (N, 1)
 MIXED_CFG_FIELDS = ("act_bits", "weight_bits", "mac_energy_pj")
+# TPU vector lane width: the layer-axis tile of the compiled kernel
+_LANES = 128
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -71,12 +73,11 @@ def resolve_pallas_interpret(interpret: bool | None = None) -> bool:
     return bool(interpret)
 
 
-def resolve_pallas_donate(donate: bool | None = None) -> bool:
-    """``None`` -> donate per-chunk config operands only on a real
-    accelerator (CPU jax can't consume donations and would warn)."""
-    if donate is None:
-        return _jax_has_accelerator()
-    return bool(donate)
+def default_tiling(n: int, l: int) -> tuple[int, int]:
+    """``(block_n, block_l)`` for ``n`` configs over ``l`` layers: config
+    tiles of up to 512 rows, and the whole layer axis as one tile up to
+    128 layers (128-wide tiles beyond) so every block shape compiles."""
+    return min(512, _ceil_to(n, 8)), (l if l <= _LANES else _LANES)
 
 
 def _sweep_block_body(*refs, n_l: int, block_l: int, w: int):
@@ -103,23 +104,31 @@ def _sweep_block_body(*refs, n_l: int, block_l: int, w: int):
                            outputs="layer_totals")
     tc = totals["total_cycles"]            # (block_n, block_l) f32
     ep = totals["energy_pj"]
-    mask = mask_ref[...]                   # (w, block_l) f32
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, block_l), 1)
 
     # Sequential compensated accumulation, one layer column at a time,
     # gated per segment: a segment's accumulator advances only on its own
     # columns, so each (config, segment) cell sees exactly the Kahan
-    # update sequence of _kahan_sum_rows over that segment's slice.
-    for j in range(block_l):
-        sel = mask[:, j][None, :] > 0.5    # (1, w): layer j's segment(s)
+    # update sequence of _kahan_sum_rows over that segment's slice.  A
+    # rolled loop keeps the compiled kernel's size independent of
+    # block_l; column j is picked out by a masked lane sum, which is exact
+    # (every other term is +0.0).
+    def _column(j, carry):
+        sel = mask_ref[pl.ds(j, 1), :] > 0.5   # (1, w): layer j's segment(s)
+        pick = col == j
         for acc_ref, cmp_ref, x in ((acc_c, cmp_c, tc),
                                     (acc_e, cmp_e, ep)):
+            xj = jnp.sum(jnp.where(pick, x, 0.0), axis=1, keepdims=True)
             acc = acc_ref[...]
             comp = cmp_ref[...]
-            y = x[:, j][:, None] - comp    # (block_n, w)
+            y = xj - comp                      # (block_n, w)
             t = acc + y
             c2 = (t - acc) - y
             acc_ref[...] = jnp.where(sel, t, acc)
             cmp_ref[...] = jnp.where(sel, c2, comp)
+        return carry
+
+    jax.lax.fori_loop(0, block_l, _column, 0)
 
     @pl.when(l_idx == n_l - 1)
     def _epilogue():
@@ -138,7 +147,7 @@ def _sweep_block_body(*refs, n_l: int, block_l: int, w: int):
 @functools.lru_cache(maxsize=64)
 def _build_sweep_call(n_pad: int, l_pad: int, w: int, block_n: int,
                       block_l: int, mixed_wide: tuple[bool, ...],
-                      interpret: bool, donate: bool):
+                      interpret: bool):
     """Compiled pallas_call for one (shape, tiling, mode) signature —
     cached so a steady-state chunk stream traces exactly once."""
     n_l = l_pad // block_l
@@ -150,7 +159,7 @@ def _build_sweep_call(n_pad: int, l_pad: int, w: int, block_n: int,
     in_specs = [cfg_block_wide if wide.get(name, False) else cfg_block
                 for name in CFG_FIELDS]
     in_specs += [lay_block for _ in LAY_FIELDS]
-    in_specs.append(pl.BlockSpec((w, block_l), lambda i, l: (0, l)))
+    in_specs.append(pl.BlockSpec((block_l, w), lambda i, l: (l, 0)))
     in_specs.append(pl.BlockSpec((1, w), lambda i, l: (0, 0)))
 
     call = pl.pallas_call(
@@ -164,10 +173,7 @@ def _build_sweep_call(n_pad: int, l_pad: int, w: int, block_n: int,
                         for _ in range(4)],
         interpret=interpret,
     )
-    # donating the per-chunk (N, ...) config operands lets steady-state
-    # streaming reuse their device buffers instead of double-buffering
-    donate_argnums = tuple(range(len(CFG_FIELDS))) if donate else ()
-    return jax.jit(call, donate_argnums=donate_argnums)
+    return jax.jit(call)
 
 
 def _pad_cfg(a: np.ndarray, n_pad: int, l_pad: int) -> np.ndarray:
@@ -192,8 +198,7 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
                             bounds: tuple[tuple[int, int], ...] | None = None,
                             block_n: int | None = None,
                             block_l: int | None = None,
-                            interpret: bool | None = None,
-                            donate: bool | None = None) -> dict:
+                            interpret: bool | None = None) -> dict:
     """Aggregate sweep columns via the Pallas kernel.
 
     ``cfg`` / ``lay`` are the float64/int64 arrays of
@@ -248,11 +253,9 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
     w = len(bounds)
 
     interpret = resolve_pallas_interpret(interpret)
-    donate = resolve_pallas_donate(donate)
-    if block_n is None:
-        block_n = min(512, _ceil_to(n, 8))
-    if block_l is None:
-        block_l = min(32, l)
+    auto_n, auto_l = default_tiling(n, l)
+    block_n = auto_n if block_n is None else block_n
+    block_l = auto_l if block_l is None else block_l
     if block_n < 1 or block_l < 1:
         raise ValueError(
             f"sweep_aggregates_pallas: block sizes must be >= 1, got "
@@ -266,9 +269,9 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
                 for name in CFG_FIELDS]
     operands += [_pad_lay(np.asarray(jlay[name]), l_pad)
                  for name in LAY_FIELDS]
-    seg_mask = np.zeros((w, l_pad), dtype=np.float32)
+    seg_mask = np.zeros((l_pad, w), dtype=np.float32)
     for wi, (s, e) in enumerate(bounds):
-        seg_mask[wi, s:e] = 1.0
+        seg_mask[s:e, wi] = 1.0
     seg_macs = np.array(
         [[jlay["macs"][0, s:e].sum(dtype=np.float32) for s, e in bounds]],
         dtype=np.float32)
@@ -277,7 +280,7 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
     mixed_wide = tuple(np.shape(cfg[name])[1] == l and l > 1
                        for name in MIXED_CFG_FIELDS)
     fn = _build_sweep_call(n_pad, l_pad, w, block_n, block_l, mixed_wide,
-                           interpret, donate)
+                           interpret)
     out = fn(*operands)                    # (n_pad, 6 * w), async
 
     result = {}

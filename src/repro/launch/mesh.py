@@ -8,26 +8,19 @@ import; everything else sees the real device count.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on mesh axes
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly "auto" on every axis
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """Version-compat ``jax.make_mesh``: pass explicit Auto ``axis_types``
-    where the installed jax supports them, plain mesh otherwise."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis explicitly ``Auto``: the compiler
+    propagates shardings, as the specs in this repo assume."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -36,7 +29,7 @@ def make_host_mesh(model: int = 1):
     model = max(1, min(model, n))
     while n % model != 0:
         model -= 1
-    return compat_make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def make_sweep_mesh(max_devices: int | None = None):
@@ -47,7 +40,7 @@ def make_sweep_mesh(max_devices: int | None = None):
     n = jax.device_count()
     if max_devices is not None:
         n = max(1, min(n, int(max_devices)))
-    return compat_make_mesh((n,), ("configs",))
+    return make_mesh((n,), ("configs",))
 
 
 def mesh_shards(mesh) -> int:
@@ -59,25 +52,3 @@ def mesh_shards(mesh) -> int:
     from repro.core.dse_batch import _mesh_shards
     return _mesh_shards(mesh)
 
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat ``shard_map`` with the replication check disabled on
-    every jax version — the sweep kernel emits replicated layer stats the
-    checker cannot verify.  The kwarg spelling moved across releases
-    (``check_rep`` -> ``check_vma``), so pick whichever the installed
-    ``shard_map`` accepts."""
-    import inspect
-    sm = jax.shard_map if hasattr(jax, "shard_map") else None
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kwargs = {}
-    try:
-        params = inspect.signature(sm).parameters
-        for name in ("check_vma", "check_rep"):
-            if name in params:
-                kwargs[name] = False
-                break
-    except (TypeError, ValueError):   # C-accelerated callable, no sig
-        kwargs["check_rep"] = False
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)

@@ -14,16 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.5 exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace, where the
-    # replication checker mishandles symbolic-zero cotangents through
-    # psum/pmean under transpose ('Zero' has no 'reshape') — disable it
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, **kw):
-        return _exp_shard_map(f, check_rep=False, **kw)
-
 from repro.parallel.sharding import shard
 from repro.quant.qlinear import qdot
 
@@ -121,7 +111,7 @@ def moe_ffn_ep(x, p, cfg, *, policy, train, capacity_factor: float = 1.25):
         aux = jax.lax.pmean(aux, db)   # varies over data axes only
         return out.reshape(b_l, s, d).astype(x_l.dtype), aux
 
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(db, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

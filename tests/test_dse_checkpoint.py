@@ -359,13 +359,16 @@ def test_jax_failure_degrades_stream_to_numpy(monkeypatch, ref_sweep):
 
     monkeypatch.setattr(dse_batch, "get_jax_kernel", boom)
     with pytest.warns(RuntimeWarning, match="degrading stream to numpy"):
-        res = _sweep_chunked(WL, [FEED], chunk_size=CHUNK, backend="jax")
+        res = _sweep_chunked(WL, [FEED], chunk_size=CHUNK, backend="jax",
+                             degrade_on_failure=True)
     assert res.backend == "numpy"
     assert res.timings["degraded"] is True
     _assert_same_sweep(res, ref_sweep)
 
 
 def test_jax_failure_raises_when_degradation_disabled(monkeypatch):
+    """Degradation is opt-in: by default a jax failure mid-stream raises
+    instead of finishing the run on the host."""
     monkeypatch.setattr(dse_batch, "resolve_backend", lambda b="auto": "jax")
     monkeypatch.setattr(dse_batch, "_require_jax_mesh", lambda mesh: None)
 
@@ -374,8 +377,7 @@ def test_jax_failure_raises_when_degradation_disabled(monkeypatch):
 
     monkeypatch.setattr(dse_batch, "get_jax_kernel", boom)
     with pytest.raises(RuntimeError, match="device wedged"):
-        _sweep_chunked(WL, [FEED], chunk_size=CHUNK, backend="jax",
-                       degrade_on_failure=False)
+        _sweep_chunked(WL, [FEED], chunk_size=CHUNK, backend="jax")
 
 
 # ---------------------------------------------------------------------------

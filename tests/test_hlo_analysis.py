@@ -5,8 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.hlo_analysis import (analyze_compiled, analyze_hlo_text,
-                                     cost_analysis_dict)
+from repro.core.hlo_analysis import analyze_compiled, analyze_hlo_text
 from repro.core.tpu_roofline import (Roofline, dense_model_flops,
                                      roofline_from_stats)
 
@@ -21,7 +20,7 @@ def test_loop_free_matches_cost_analysis():
     co = _compile(g, jax.ShapeDtypeStruct((256, 512), jnp.float32),
                   jax.ShapeDtypeStruct((512, 128), jnp.float32))
     mc = analyze_hlo_text(co.as_text())
-    xla = cost_analysis_dict(co)["flops"]
+    xla = co.cost_analysis()["flops"]
     expect = 2 * 256 * 512 * 128
     assert abs(mc.flops - expect) / expect < 0.02
     assert abs(mc.flops - xla) / xla < 0.02
@@ -44,7 +43,7 @@ def test_scan_trip_count_correction():
     expect = 2 * 8 * 64 * 64 * L * 3
     assert abs(mc.flops - expect) / expect < 0.10, mc.flops
     # XLA counts the body once -> must be way below our corrected count
-    assert cost_analysis_dict(co)["flops"] < mc.flops / 2
+    assert co.cost_analysis()["flops"] < mc.flops / 2
 
 
 def test_analyze_compiled_fields():

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import (activation_sharding,
                                      default_activation_rules, param_pspec,
                                      shard, tree_pspecs)
@@ -68,7 +68,7 @@ def test_activation_sharding_context_noop_outside():
 
 
 def test_activation_sharding_applies_inside():
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = default_activation_rules(mesh, seq_sharded=True)
 
     def f(x):
@@ -80,6 +80,6 @@ def test_activation_sharding_applies_inside():
 
 
 def test_default_rules_shapes():
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rules = default_activation_rules(mesh, seq_sharded=False)
     assert "residual" in rules and "moe_buffer" in rules

@@ -23,8 +23,7 @@ from repro.core.dse_batch import (AGGREGATE_OUTPUTS, _make_cfg_lay,
 from repro.core.pe import PEType
 from repro.core.synthesis import synthesize_soa
 from repro.core.workloads import get_workload
-from repro.kernels.sweep_kernel import (CFG_FIELDS, resolve_pallas_donate,
-                                        resolve_pallas_interpret,
+from repro.kernels.sweep_kernel import (resolve_pallas_interpret,
                                         sweep_aggregates_pallas)
 
 RTOL = 1e-6
@@ -174,15 +173,14 @@ def test_validation_guards():
 
 
 def test_mode_resolution_cpu():
-    """On the CPU-only CI host: interpret auto-resolves on, donation
-    auto-resolves off (CPU jax can't consume donations)."""
+    """On the CPU-only CI host interpret mode auto-resolves on; an
+    explicit bool wins."""
     from repro.core.dse_batch import _jax_has_accelerator
     if _jax_has_accelerator():          # pragma: no cover - device CI
         pytest.skip("accelerator attached")
     assert resolve_pallas_interpret(None) is True
-    assert resolve_pallas_donate(None) is False
     assert resolve_pallas_interpret(False) is False
-    assert resolve_pallas_donate(True) is True
+    assert resolve_pallas_interpret(True) is True
 
 
 def test_resolve_use_pallas_routing():
