@@ -1,0 +1,10 @@
+"""Host side of the Pallas sweep-kernel call per evaluation in the
+resnet50.serving cell (operand conversion and padding, segment mask, the
+jitted call, the six output slices): mean duration of the kernel.launch
+spans, ms."""
+
+from harness.tracing import mean_ms
+
+
+def read(run):
+    return mean_ms(run.spans, "kernel.launch")

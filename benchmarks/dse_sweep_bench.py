@@ -142,9 +142,8 @@ def bench_chunked(workload: str, quick: bool) -> dict:
                 best_res.timings["kernel_wait_s"]
             out[f"chunked_{backend}_front_size"] = res.front_size
         # depth-k prefetch scaling: one timed run per depth, with the
-        # stage accounting (sweep.kernel / sweep.synthesize span sums,
-        # surfaced through timings) turned into device-side throughput
-        # and per-depth overlap fraction
+        # stage accounting (synth_s / kernel_wait_s, surfaced through
+        # timings) turned into a per-depth overlap fraction
         for depth in (1, 2, 4):
             t0 = time.perf_counter()
             res = sweep_chunked(wl, space(), backend=backend,
@@ -155,13 +154,6 @@ def bench_chunked(workload: str, quick: bool) -> dict:
             tm = res.timings
             out[f"chunked_{backend}_depth{depth}_s"] = dt
             out[f"chunked_{backend}_depth{depth}_configs_per_s"] = n / dt
-            # configs over kernel-stage busy time (dispatch -> finalize
-            # span of every chunk): the accelerator-bound ceiling the
-            # prefetch queue is trying to reach
-            busy = tm["kernel_busy_s"]
-            if busy > 0:
-                out[f"chunked_{backend}_depth{depth}"
-                    f"_device_configs_per_s"] = n / busy
             # stage overlap: (synth + kernel_wait) / wall > 1 means the
             # host and kernel stages ran concurrently (cf. obs report)
             wall = tm["wall_s"]
@@ -413,10 +405,8 @@ def main() -> None:
         for d in (1, 2, 4):
             dk = f"chunked_{b}_depth{d}_configs_per_s"
             if dk in r:
-                dev = r.get(f"chunked_{b}_depth{d}_device_configs_per_s")
                 ov = r.get(f"chunked_{b}_depth{d}_overlap_fraction")
                 print(f"  depth={d}   {r[dk]:9.0f} configs/s"
-                      + (f"  device {dev:9.0f}/s" if dev else "")
                       + (f"  stage overlap {ov:.0%}"
                          if ov is not None else ""))
     if r.get("pallas_available"):

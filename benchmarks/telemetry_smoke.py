@@ -5,8 +5,9 @@ Four gates:
 
 * **trace validity** — an instrumented ``sweep_chunked`` + ``nsga2`` run
   exports Chrome ``trace_event`` JSON that passes the schema check and
-  carries the expected stage spans (pull / synthesize / dispatch /
-  kernel / reduce, per-generation spans, evaluate spans).
+  carries the expected stage spans (pull / synthesize / synthesis math /
+  dispatch / kernel wait / reduce, per-generation spans, evaluate
+  spans).
 * **metrics content** — the registry snapshot after the run has
   per-stage times (``sweep.synth_s`` / ``sweep.kernel_wait_s`` /
   ``sweep.wall_s``), synthesis-cache hit/miss counters, and the evals/s
@@ -49,7 +50,8 @@ GRID = dict(glb_kbs=(64, 128, 256, 512),
             bws=tuple(float(b) for b in np.linspace(2.0, 64.0, 24)))
 
 EXPECTED_SWEEP_SPANS = ("sweep_chunked", "sweep.pull", "sweep.synthesize",
-                        "sweep.dispatch", "sweep.kernel", "sweep.reduce")
+                        "synth.model", "sweep.dispatch", "kernel.wait",
+                        "sweep.reduce")
 EXPECTED_SEARCH_SPANS = ("nsga2.generation", "explore.evaluate")
 
 

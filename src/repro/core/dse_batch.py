@@ -514,7 +514,8 @@ def _run_kernel(cfg: dict, lay: dict, backend: str,
         # keep the jitted XLA kernel
         from repro.kernels.sweep_kernel import sweep_aggregates_pallas
         out = sweep_aggregates_pallas(cfg, lay)
-        return {k: np.asarray(v) for k, v in out.items()}
+        with obs_trace.span("kernel.wait"):
+            return {k: np.asarray(v) for k, v in out.items()}
     if backend == "jax":
         _require_jax_mesh(mesh)
         fn, exact = get_jax_kernel(mesh, outputs)
@@ -1013,9 +1014,9 @@ def _sweep_mixed_many(workloads: Sequence[Workload],
     cfg = mixed_assign_cfg(cfg, assign_all)
     if backend == "jax" and use_pallas:
         from repro.kernels.sweep_kernel import sweep_aggregates_pallas
-        out = {k: np.asarray(v)
-               for k, v in sweep_aggregates_pallas(
-                   cfg, lay, bounds=bounds).items()}
+        out = sweep_aggregates_pallas(cfg, lay, bounds=bounds)
+        with obs_trace.span("kernel.wait"):
+            out = {k: np.asarray(v) for k, v in out.items()}
     elif backend == "jax":
         _require_jax_mesh(mesh)
         fn, exact = get_jax_many_kernel(bounds, mesh)
@@ -1395,7 +1396,7 @@ def _sweep_chunked(workload: Workload,
     t_wall = time.perf_counter()
     timings = {"overlap": bool(overlap), "prefetch_depth": depth,
                "use_pallas": bool(use_pallas), "wall_s": 0.0,
-               "synth_s": 0.0, "kernel_wait_s": 0.0, "kernel_busy_s": 0.0,
+               "synth_s": 0.0, "kernel_wait_s": 0.0,
                "watchdog_redispatches": 0, "executor_replacements": 0,
                "cancelled_recomputes": 0, "abandoned_finalizers": 0,
                "degraded": False}
@@ -1425,7 +1426,6 @@ def _sweep_chunked(workload: Workload,
         _reg.inc("sweep.wall_s", timings["wall_s"])
         _reg.inc("sweep.synth_s", timings["synth_s"])
         _reg.inc("sweep.kernel_wait_s", timings["kernel_wait_s"])
-        _reg.inc("sweep.kernel_busy_s", timings["kernel_busy_s"])
         _reg.set("sweep.prefetch_depth", depth)
         if status != "ok":
             _reg.inc("sweep.failures")
@@ -1505,18 +1505,18 @@ def _sweep_chunked(workload: Workload,
 
     # FIFO of in-flight chunks, each:
     # (soa, n, cfg, lay, finalize, backend_at_dispatch, save_info,
-    #  cache_state, chunk_index, kernel_span, t_dispatch)
+    #  cache_state, chunk_index)
     pending: deque = deque()
 
     def drain_one() -> None:
         if not pending:
             return
         (psoa, pn, pcfg, play, pfin, pbackend, psave, pcache,
-         pci, kspan, tdisp) = pending.popleft()
+         pci) = pending.popleft()
         t0 = time.perf_counter()
-        kstatus = "ok"
         try:
-            out = pfin(timeout=chunk_deadline_s)
+            with obs_trace.span("kernel.wait", chunk=pci):
+                out = pfin(timeout=chunk_deadline_s)
         except ChunkDeadlineExceeded:
             warnings.warn(
                 f"chunk kernel exceeded the {chunk_deadline_s:.3g}s "
@@ -1527,7 +1527,6 @@ def _sweep_chunked(workload: Workload,
             _reg.inc("sweep.watchdog_redispatches")
             if pbackend == "jax":
                 timings["abandoned_finalizers"] += 1
-            kstatus = "watchdog"
             # the deadlined worker (numpy path) is a zombie occupying
             # the 1-worker executor — replace it so the next dispatch
             # doesn't queue behind it and cascade-deadline
@@ -1539,21 +1538,13 @@ def _sweep_chunked(workload: Workload,
             # down; recompute serially, no deadline of its own
             timings["cancelled_recomputes"] += 1
             _reg.inc("sweep.cancelled_recomputes")
-            kstatus = "cancelled"
             with obs_trace.span("sweep.cancelled_recompute", chunk=pci):
                 out = _sweep_kernel(np, pcfg, play, outputs="aggregates")
         except Exception as exc:
             if pbackend != "jax" or not degrade_on_failure:
-                obs_trace.span_end(kspan, status="error")
                 raise
-            kstatus = "degraded"
             out = _degrade(pcfg, play, exc, "materialization")
-        now = time.perf_counter()
-        timings["kernel_wait_s"] += now - t0
-        # dispatch -> finalize span of this chunk: the kernel stage's
-        # busy time (overlapping in-flight chunks each count their own)
-        timings["kernel_busy_s"] += now - tdisp
-        obs_trace.span_end(kspan, status=kstatus)
+        timings["kernel_wait_s"] += time.perf_counter() - t0
         with obs_trace.span("sweep.reduce", chunk=pci, n=pn):
             reduce_chunk(psoa, pn, out)
         if psave is not None:
@@ -1615,8 +1606,6 @@ def _sweep_chunked(workload: Workload,
                     if cache is not None:
                         cache_state = cache.export_state()
                 # stage 2 (device / worker thread): dispatch the kernel
-                kspan = obs_trace.span_start("sweep.kernel", chunk=ci,
-                                             n=n, backend=backend)
                 try:
                     with obs_trace.span("sweep.dispatch", chunk=ci):
                         finalize = _dispatch_chunk(cfg, lay, backend,
@@ -1624,13 +1613,11 @@ def _sweep_chunked(workload: Workload,
                                                    executor, use_pallas)
                 except Exception as exc:
                     if backend != "jax" or not degrade_on_failure:
-                        obs_trace.span_end(kspan, status="error")
                         raise
                     out_now = _degrade(cfg, lay, exc, "dispatch")
                     finalize = lambda timeout=None, o=out_now: o  # noqa: E731
                 pending.append((soa, n, cfg, lay, finalize, backend,
-                                save_info, cache_state, ci, kspan,
-                                time.perf_counter()))
+                                save_info, cache_state, ci))
                 _reg.observe("sweep.inflight", len(pending))
                 # bounded prefetch: drain FIFO until at most depth-1
                 # chunks stay in flight behind the next synthesis

@@ -34,6 +34,7 @@ from repro.core.dataflow import leakage_mw_soa
 from repro.core.pe import (rf_access_energy_pj, sram_access_energy_pj,
                            sram_area_um2)
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 @dataclasses.dataclass(frozen=True)
 class SynthesisReport:
@@ -473,17 +474,21 @@ class PersistentSynthesisCache:
                    ) -> dict[str, np.ndarray]:
         """Cache-through batched synthesis: hit rows gather from the
         store, miss rows run :func:`synthesize_soa` and are inserted."""
-        digests = config_digests(soa)
-        mask, cols = self.lookup(digests)
+        with obs_trace.span("synth.digest"):
+            digests = config_digests(soa)
+        with obs_trace.span("synth.lookup"):
+            mask, cols = self.lookup(digests)
         miss = ~mask
         if miss.any():
             idx = np.nonzero(miss)[0]
-            sub = {k: v[idx] for k, v in soa.items()}
-            fresh = synthesize_soa(sub, digests=tuple(d[idx]
-                                                      for d in digests))
-            for c in REPORT_COLUMNS:
-                cols[c][idx] = fresh[c]
-            self.insert(tuple(d[idx] for d in digests), fresh)
+            with obs_trace.span("synth.model", n=len(idx)):
+                sub = {k: v[idx] for k, v in soa.items()}
+                fresh = synthesize_soa(sub, digests=tuple(d[idx]
+                                                          for d in digests))
+                for c in REPORT_COLUMNS:
+                    cols[c][idx] = fresh[c]
+            with obs_trace.span("synth.insert"):
+                self.insert(tuple(d[idx] for d in digests), fresh)
         return cols
 
 

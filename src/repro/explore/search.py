@@ -316,22 +316,23 @@ class Evaluator:
                                     use_pallas=self.use_pallas)
             agg = {k: np.asarray(v)[:, :n_real]
                    for k, v in agg.items() if np.ndim(v) == 2}
-            return multi_objective_matrix(
-                agg, [a[:n_real] for a in assigns], macs,
-                self.objectives, weights=self.weights,
-                accuracy=self.accuracy)
+            with obs_trace.span("explore.objectives"):
+                return multi_objective_matrix(
+                    agg, [a[:n_real] for a in assigns], macs,
+                    self.objectives, weights=self.weights,
+                    accuracy=self.accuracy)
         wl, = wls
         agg = _sweep_mixed(wl, soa, assign[:, :len(wl.layers)],
                            use_cache=self.use_cache,
                            backend=self.backend, outputs="aggregates",
                            mesh=self.mesh, use_pallas=self.use_pallas)
-        return objective_matrix({k: np.asarray(v)[:n_real]
-                                 for k, v in agg.items()},
-                                assign[:n_real, :len(wl.layers)],
-                                macs[0], self.objectives,
-                                traffic=self.traffic,
-                                n_slots=self.n_slots,
-                                accuracy=self.accuracy)
+        agg = {k: np.asarray(v)[:n_real] for k, v in agg.items()}
+        with obs_trace.span("explore.objectives"):
+            return objective_matrix(agg, assign[:n_real, :len(wl.layers)],
+                                    macs[0], self.objectives,
+                                    traffic=self.traffic,
+                                    n_slots=self.n_slots,
+                                    accuracy=self.accuracy)
 
     def evaluate(self, genomes: np.ndarray,
                  subset: int | None = None) -> np.ndarray:
@@ -676,7 +677,8 @@ def nsga2(space: CoExploreSpace, workload, budget: int, *,
         n_off = min(pop_size, budget - evals)
         with obs_trace.span("nsga2.generation", gen=gen + 1,
                             evals=evals, n_off=n_off):
-            ranks, crowd = _ranks_and_crowding(F)
+            with obs_trace.span("nsga2.rank"):
+                ranks, crowd = _ranks_and_crowding(F)
             p1 = _tournament(rng, n_off, ranks, crowd)
             p2 = _tournament(rng, n_off, ranks, crowd)
             children = space.crossover(pop[p1], pop[p2], rng)
@@ -685,27 +687,31 @@ def nsga2(space: CoExploreSpace, workload, budget: int, *,
             evals += n_off
             gen += 1
             all_F.append(Fc)
-            if eps_archive is not None:
-                eps_archive.add(children, Fc)
-                arch_g = eps_archive.genomes
-                arch_F = eps_archive.objectives
-            else:
-                comb_g = np.concatenate([arch_g, children])
-                comb_F = np.concatenate([arch_F, Fc])
-                # a genome re-visited across generations has an identical
-                # memoized objective row; keep one copy (first occurrence)
-                # so the archive stays the *set* of non-dominated genomes
-                # found
-                _, uidx = np.unique(comb_g, axis=0, return_index=True)
-                uidx.sort()
-                arch_g, arch_F = _front(comb_g[uidx], comb_F[uidx])
+            with obs_trace.span("nsga2.archive"):
+                if eps_archive is not None:
+                    eps_archive.add(children, Fc)
+                    arch_g = eps_archive.genomes
+                    arch_F = eps_archive.objectives
+                else:
+                    comb_g = np.concatenate([arch_g, children])
+                    comb_F = np.concatenate([arch_F, Fc])
+                    # a genome re-visited across generations has an
+                    # identical memoized objective row; keep one copy
+                    # (first occurrence) so the archive stays the *set*
+                    # of non-dominated genomes found
+                    _, uidx = np.unique(comb_g, axis=0, return_index=True)
+                    uidx.sort()
+                    arch_g, arch_F = _front(comb_g[uidx], comb_F[uidx])
             comb = np.concatenate([pop, children])
             Fcomb = np.concatenate([F, Fc])
-            ranks2, crowd2 = _ranks_and_crowding(Fcomb)
+            with obs_trace.span("nsga2.rank"):
+                ranks2, crowd2 = _ranks_and_crowding(Fcomb)
             order = np.lexsort((np.arange(len(comb)), -crowd2, ranks2))
             sel = order[:pop_size]
             pop, F = comb[sel], Fcomb[sel]
-            history.append((evals, hypervolume(arch_F, ref)))
+            with obs_trace.span("nsga2.hypervolume"):
+                hv = hypervolume(arch_F, ref)
+            history.append((evals, hv))
         reg.inc("nsga2.generations")
         reg.set("nsga2.archive_size", int(len(arch_F)))
         if ckpt is not None and ckpt.should_save(gen,

@@ -47,6 +47,7 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.dse_batch import (AGGREGATE_OUTPUTS, _jax_has_accelerator,
                                   _sweep_kernel, _to_jax_inputs)
+from repro.obs import trace as obs_trace
 
 # operand order of the pallas_call — every cfg/lay field the mapping +
 # energy model reads, one ref each (dicts don't cross the pallas boundary)
@@ -261,30 +262,32 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
             f"sweep_aggregates_pallas: block sizes must be >= 1, got "
             f"block_n={block_n}, block_l={block_l}")
 
-    jcfg, jlay = _to_jax_inputs(cfg, lay, exact=False)
-    n_pad = _ceil_to(n, block_n)
-    l_pad = _ceil_to(l, block_l)
+    with obs_trace.span("kernel.launch", n=n, l=l, w=w):
+        jcfg, jlay = _to_jax_inputs(cfg, lay, exact=False)
+        n_pad = _ceil_to(n, block_n)
+        l_pad = _ceil_to(l, block_l)
 
-    operands = [_pad_cfg(np.asarray(jcfg[name]), n_pad, l_pad)
-                for name in CFG_FIELDS]
-    operands += [_pad_lay(np.asarray(jlay[name]), l_pad)
-                 for name in LAY_FIELDS]
-    seg_mask = np.zeros((l_pad, w), dtype=np.float32)
-    for wi, (s, e) in enumerate(bounds):
-        seg_mask[s:e, wi] = 1.0
-    seg_macs = np.array(
-        [[jlay["macs"][0, s:e].sum(dtype=np.float32) for s, e in bounds]],
-        dtype=np.float32)
-    operands += [seg_mask, seg_macs]
+        operands = [_pad_cfg(np.asarray(jcfg[name]), n_pad, l_pad)
+                    for name in CFG_FIELDS]
+        operands += [_pad_lay(np.asarray(jlay[name]), l_pad)
+                     for name in LAY_FIELDS]
+        seg_mask = np.zeros((l_pad, w), dtype=np.float32)
+        for wi, (s, e) in enumerate(bounds):
+            seg_mask[s:e, wi] = 1.0
+        seg_macs = np.array(
+            [[jlay["macs"][0, s:e].sum(dtype=np.float32)
+              for s, e in bounds]],
+            dtype=np.float32)
+        operands += [seg_mask, seg_macs]
 
-    mixed_wide = tuple(np.shape(cfg[name])[1] == l and l > 1
-                       for name in MIXED_CFG_FIELDS)
-    fn = _build_sweep_call(n_pad, l_pad, w, block_n, block_l, mixed_wide,
-                           interpret)
-    out = fn(*operands)                    # (n_pad, 6 * w), async
+        mixed_wide = tuple(np.shape(cfg[name])[1] == l and l > 1
+                           for name in MIXED_CFG_FIELDS)
+        fn = _build_sweep_call(n_pad, l_pad, w, block_n, block_l,
+                               mixed_wide, interpret)
+        out = fn(*operands)                # (n_pad, 6 * w), async
 
-    result = {}
-    for idx, name in enumerate(AGGREGATE_OUTPUTS):
-        block = out[:n, idx * w:(idx + 1) * w]     # (N, W)
-        result[name] = block[:, 0] if squeeze else block.T
+        result = {}
+        for idx, name in enumerate(AGGREGATE_OUTPUTS):
+            block = out[:n, idx * w:(idx + 1) * w]     # (N, W)
+            result[name] = block[:, 0] if squeeze else block.T
     return result

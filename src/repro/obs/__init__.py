@@ -37,7 +37,6 @@ from .trace import (
     span,
     span_end,
     span_start,
-    timed_span,
     validate_chrome_trace,
 )
 
@@ -63,6 +62,5 @@ __all__ = [
     "span_end",
     "span_start",
     "summarize",
-    "timed_span",
     "validate_chrome_trace",
 ]

@@ -51,13 +51,6 @@ def _derived(metrics: dict) -> dict:
         if synth + wait > 0:
             d["sweep_overlap_fraction"] = max(
                 0.0, min(1.0, (synth + wait) / wall - 1.0))
-    # device-side throughput: configs over time the kernel was actually
-    # executing (busy), not the host wall — the accelerator-bound number
-    # the depth-k prefetch queue is trying to saturate
-    busy = metrics.get("sweep.kernel_busy_s", 0.0)
-    if busy:
-        d["sweep_device_configs_per_s"] = (
-            metrics.get("sweep.configs", 0) / busy)
     # mean prefetch-queue occupancy: sweep.inflight is a histogram
     # observed once per dispatched chunk; its mean is how many finalize
     # handles the depth-k queue actually kept in flight

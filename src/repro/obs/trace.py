@@ -12,10 +12,9 @@ Two recording APIs:
 
 * ``with span("synthesize", chunk=i):`` — the common nested form; spans
   nest per thread, and each records its parent and depth.
-* ``h = span_start("kernel", chunk=i)`` / ``span_end(h)`` — explicit
+* ``h = span_start("sweep_chunked")`` / ``span_end(h)`` — explicit
   start/stop for work whose begin and end live in different scopes
-  (async kernel dispatch: started at dispatch, ended when the stream
-  drains the chunk).
+  (a whole stream, closed from its success or its error path).
 
 **The disabled path is a no-op**: ``span()`` returns a shared singleton
 context manager and ``span_start`` returns ``None`` — no allocation, no
@@ -25,7 +24,12 @@ the *enabled* overhead at <2% on a real sweep).
 
 ``configure(jax_annotations=True)`` additionally wraps every
 context-manager span in ``jax.profiler.TraceAnnotation``, so the same
-stage names show up inside XLA device profiles.
+stage names show up inside XLA device profiles.  Every span also stamps
+its start and end as ``t0_ns`` / ``t1_ns`` in ``time.time_ns()`` units,
+the profiler's own clock: a span's ``t0_ns`` less the
+``profile_start_time`` of the profile's ``Task Environment`` plane is
+the ``start_ns`` of its mirrored xplane event, to a few microseconds
+(the event opens just before the span's clock read).
 
 Exports: :func:`export_chrome_trace` writes the standard
 ``{"traceEvents": [...]}`` Chrome ``trace_event`` document (loadable in
@@ -48,11 +52,15 @@ class Span:
     """One closed (or still-open) traced interval."""
 
     __slots__ = ("span_id", "parent_id", "name", "t0_s", "dur_s",
-                 "cpu_dur_s", "tid", "depth", "attrs", "status",
-                 "_cpu0_s")
+                 "cpu_dur_s", "t0_ns", "t1_ns", "tid", "depth", "attrs",
+                 "status", "_cpu0_s")
 
     def __init__(self, span_id: int, parent_id: int | None, name: str,
                  t0_s: float, tid: int, depth: int, attrs: dict):
+        # start and end on the profiler's clock: unix nanoseconds, the
+        # clock jax.profiler stamps (profile_start_time + xplane start_ns)
+        self.t0_ns = time.time_ns()
+        self.t1_ns: int | None = None
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
@@ -77,6 +85,8 @@ class Span:
             "t0_s": self.t0_s,
             "dur_s": self.dur_s,
             "cpu_dur_s": self.cpu_dur_s,
+            "t0_ns": self.t0_ns,
+            "t1_ns": self.t1_ns,
             "tid": self.tid,
             "depth": self.depth,
             "status": self.status,
@@ -108,7 +118,8 @@ _NOOP = _NoopSpan()
 class _SpanCtx:
     """Context-manager wrapper that opens/closes one traced span (and,
     when configured, a ``jax.profiler.TraceAnnotation`` of the same
-    name)."""
+    name, opened just before the span's clock reads and closed just
+    after, so its profiler event brackets the span)."""
 
     __slots__ = ("_tracer", "_name", "_attrs", "_span", "_jax_ctx")
 
@@ -120,8 +131,6 @@ class _SpanCtx:
         self._jax_ctx = None
 
     def __enter__(self) -> Span:
-        self._span = self._tracer.start(self._name, self._attrs,
-                                        on_stack=True)
         ann = _STATE["jax_annotation"]
         if ann is not None:
             try:
@@ -129,15 +138,17 @@ class _SpanCtx:
                 self._jax_ctx.__enter__()
             except Exception:       # device profiler not active / usable
                 self._jax_ctx = None
+        self._span = self._tracer.start(self._name, self._attrs,
+                                        on_stack=True)
         return self._span
 
     def __exit__(self, exc_type, exc, tb):
-        if self._jax_ctx is not None:
-            with contextlib.suppress(Exception):
-                self._jax_ctx.__exit__(exc_type, exc, tb)
         self._tracer.end(self._span,
                          status="error" if exc_type is not None else "ok",
                          pop_stack=True)
+        if self._jax_ctx is not None:
+            with contextlib.suppress(Exception):
+                self._jax_ctx.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -191,6 +202,7 @@ class Tracer:
     def end(self, sp: Span, *, status: str = "ok",
             pop_stack: bool = False) -> None:
         sp.dur_s = time.perf_counter() - self.epoch_s - sp.t0_s
+        sp.t1_ns = time.time_ns()
         sp.cpu_dur_s = time.process_time() - sp._cpu0_s
         sp.status = status
         if pop_stack:
@@ -353,9 +365,9 @@ def span(name: str, **attrs):
 
 
 def span_start(name: str, **attrs) -> Span | None:
-    """Open an *un-stacked* span for work that ends in another scope
-    (async kernel dispatch).  Returns ``None`` while disabled — pass the
-    handle straight to :func:`span_end`, which ignores ``None``."""
+    """Open an *un-stacked* span for work that ends in another scope.
+    Returns ``None`` while disabled — pass the handle straight to
+    :func:`span_end`, which ignores ``None``."""
     if not _STATE["enabled"]:
         return None
     return _TRACER.start(name, attrs)
@@ -370,40 +382,6 @@ def span_end(handle: Span | None, *, status: str = "ok", **attrs) -> None:
     _TRACER.end(handle, status=status)
 
 
-class timed_span:
-    """Span that *also* accumulates its wall duration into a plain dict —
-    the bridge that lets legacy ``timings``-style accounting be populated
-    by the same clock reads as the trace (``sink[key] += dur``).  Always
-    times (the sink needs the number either way); records a span only
-    while tracing is enabled.
-    """
-
-    __slots__ = ("_name", "_attrs", "_sink", "_key", "_t0", "_ctx")
-
-    def __init__(self, name: str, sink: dict | None = None,
-                 key: str | None = None, **attrs):
-        self._name = name
-        self._attrs = attrs
-        self._sink = sink
-        self._key = key
-        self._ctx = None
-
-    def __enter__(self):
-        if _STATE["enabled"]:
-            self._ctx = _SpanCtx(_TRACER, self._name, self._attrs)
-            self._ctx.__enter__()
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
-        if self._sink is not None:
-            self._sink[self._key] = self._sink.get(self._key, 0.0) + dur
-        if self._ctx is not None:
-            self._ctx.__exit__(exc_type, exc, tb)
-        return False
-
-
 # ---------------------------------------------------------------------------
 # Exporters
 # ---------------------------------------------------------------------------
@@ -412,7 +390,8 @@ def export_chrome_trace(path=None, *, tracer: Tracer | None = None) -> dict:
     """Render the ring as a Chrome ``trace_event`` document.
 
     Complete spans become ``"ph": "X"`` duration events (microsecond
-    timestamps relative to the tracer epoch); thread ids are remapped to
+    timestamps relative to the tracer epoch, and each span's ``t0_ns``
+    on the profiler's clock in its ``args``); thread ids are remapped to
     small ints in first-seen order so Perfetto's track names stay
     readable.  When ``path`` is given the document is also written there
     as JSON.  Loadable in ``chrome://tracing`` / https://ui.perfetto.dev.
@@ -432,7 +411,7 @@ def export_chrome_trace(path=None, *, tracer: Tracer | None = None) -> dict:
             "tid": tid,
             "args": dict(sp.attrs, span_id=sp.span_id,
                          parent_id=sp.parent_id, status=sp.status,
-                         cpu_dur_s=sp.cpu_dur_s),
+                         cpu_dur_s=sp.cpu_dur_s, t0_ns=sp.t0_ns),
         })
     doc = {
         "traceEvents": events,

@@ -322,11 +322,6 @@ def simulate_fleet(step_s, e_token_j, traffic, *, n_slots: int = 8,
     served = res.served
     if served.size:
         reg.set("fleet.served_frac", float(served.mean()))
-        if obs_trace.is_enabled():
-            # percentile math over (N, R) is not free — only pay for the
-            # SLO gauge when telemetry is actually on
-            reg.set("fleet.slo_attainment",
-                    float(res.metrics()["slo_attainment"].mean()))
     return res
 
 

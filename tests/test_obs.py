@@ -16,6 +16,7 @@ Layers under test:
 """
 
 import json
+import time
 
 import numpy as np
 import pytest
@@ -125,16 +126,52 @@ def test_ring_bound_evicts_oldest():
     obs.configure(enabled=False, ring_size=65536)
 
 
-def test_timed_span_populates_sink_always():
-    sink = {}
-    with obs.timed_span("stage", sink=sink, key="synth_s"):
-        pass
-    assert sink["synth_s"] >= 0.0      # timed even while disabled
-    assert obs.get_tracer().spans() == []
+def test_spans_stamp_the_profiler_clock():
+    """Stacked spans and span_start/span_end handles both carry t0_ns /
+    t1_ns in time.time_ns() units, bracketing their wall duration."""
     obs.configure(enabled=True)
-    with obs.timed_span("stage", sink=sink, key="synth_s"):
-        pass
-    assert len(obs.get_tracer().spans("stage")) == 1
+    lo = time.time_ns()
+    with obs.span("stacked"):
+        h = obs.span_start("handle")
+        time.sleep(0.002)
+        obs.span_end(h)
+    hi = time.time_ns()
+    for sp in obs.get_tracer().spans():
+        d = sp.as_dict()
+        assert lo <= d["t0_ns"] <= d["t1_ns"] <= hi
+        assert abs((d["t1_ns"] - d["t0_ns"]) * 1e-9 - d["dur_s"]) < 1e-3
+    doc = obs.export_chrome_trace()
+    assert all(e["args"]["t0_ns"] >= lo for e in doc["traceEvents"])
+
+
+def test_span_t0_ns_matches_its_profiler_event(tmp_path):
+    """A span's t0_ns, less the profile's start time, is the start of
+    its mirrored TraceAnnotation event in the xplane."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    obs.configure(enabled=True, jax_annotations=True, reset=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for i in range(4):
+            with obs.span(f"clock.{i}"):
+                jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+        obs.disable()
+    path, = tmp_path.rglob("*.xplane.pb")
+    planes = list(ProfileData.from_file(str(path)).planes)
+    start_ns = dict(next(p for p in planes
+                         if p.name == "Task Environment").stats)[
+        "profile_start_time"]
+    events = {e.name: e.start_ns for p in planes for ln in p.lines
+              for e in ln.events if e.name.startswith("clock.")}
+    spans = obs.get_tracer().spans()
+    assert len(spans) == 4 and set(events) == {s.name for s in spans}
+    for sp in spans:
+        assert abs(start_ns + events[sp.name] - sp.t0_ns) < 1e6
 
 
 def test_configured_scoping_restores_prior_state(tmp_path):
@@ -301,7 +338,8 @@ def test_bit_identity_telemetry_on_vs_off(backend, jax_usable):
         assert np.array_equal(on.front_soa[k], ref.front_soa[k])
     # the instrumented run actually recorded the stage spans
     names = {s.name for s in obs.get_tracer().spans()}
-    assert {"sweep_chunked", "sweep.synthesize", "sweep.kernel",
+    assert {"sweep_chunked", "sweep.synthesize", "synth.digest",
+            "synth.lookup", "synth.model", "synth.insert", "kernel.wait",
             "sweep.reduce"} <= names
 
 
@@ -425,3 +463,126 @@ def test_explore_spec_telemetry_field(tmp_path):
                                  seed=3, backend="numpy"))
     assert np.array_equal(res.genomes, res2.genomes)
     assert np.array_equal(res.front_objectives, res2.front_objectives)
+
+
+# ---------------------------------------------------------------------------
+# spans where the work happens: synthesis, the kernel call, nsga2
+# ---------------------------------------------------------------------------
+
+SYNTH_PHASES = ("synth.digest", "synth.lookup", "synth.model",
+                "synth.insert")
+
+
+def _tree(spans):
+    """(span dicts by id, children dicts by parent id); checks that no
+    span's children outlast it."""
+    by_id = {s["span_id"]: s for s in spans}
+    kids: dict = {}
+    for s in spans:
+        kids.setdefault(s["parent_id"], []).append(s)
+    for pid, cs in kids.items():
+        if pid is not None:
+            assert sum(c["dur_s"] for c in cs) <= by_id[pid]["dur_s"]
+    return by_id, kids
+
+
+def _names_under(kids, parent) -> list[str]:
+    return [c["name"] for c in kids.get(parent["span_id"], [])]
+
+
+def _traced(fn):
+    obs.configure(enabled=True, reset=True)
+    try:
+        out = fn()
+    finally:
+        obs.disable()
+    return out, [s.as_dict() for s in obs.get_tracer().spans()]
+
+
+def _pallas_feed():
+    from repro.core.accelerator import AcceleratorConfig
+    from repro.core.pe import PEType
+    rng = np.random.default_rng(5)
+    types = tuple(PEType)
+    return [AcceleratorConfig(
+        pe_type=types[int(rng.integers(len(types)))],
+        pe_rows=int(rng.integers(4, 33)), pe_cols=int(rng.integers(4, 33)),
+        glb_kb=int(rng.choice([64, 128, 256, 512])),
+        dram_bw_gbps=float(rng.choice([6.4, 12.8, 25.6])))
+        for _ in range(36)]
+
+
+@pytest.mark.parametrize("path", ["numpy", "pallas-interpret"])
+def test_synthesis_phases_nest_under_sweep_synthesize(path, jax_usable):
+    if path != "numpy" and not jax_usable:
+        pytest.skip("jax unusable on this host")
+    wl = get_workload("vgg16")
+    if path == "numpy":
+        res, spans = _traced(lambda: _sweep_chunked(
+            wl, _space(), backend="numpy", chunk_size=CHUNK,
+            cache=PersistentSynthesisCache(), save_cache=False))
+    else:
+        res, spans = _traced(lambda: _sweep_chunked(
+            wl, [_pallas_feed()], backend="jax", use_pallas=True,
+            chunk_size=16, cache=PersistentSynthesisCache(),
+            save_cache=False))
+    by_id, kids = _tree(spans)
+    synth = [s for s in spans if s["name"] == "sweep.synthesize"]
+    assert len(synth) == res.n_chunks
+    for s in synth:
+        assert _names_under(kids, s) == list(SYNTH_PHASES)
+    waits = [s for s in spans if s["name"] == "kernel.wait"]
+    assert sorted(w["attrs"]["chunk"] for w in waits) == list(
+        range(res.n_chunks))
+    launches = [s for s in spans if s["name"] == "kernel.launch"]
+    if path == "numpy":
+        assert launches == []
+        return
+    assert len(launches) == res.n_chunks
+    for s in launches:
+        assert by_id[s["parent_id"]]["name"] == "sweep.dispatch"
+        assert (s["attrs"]["n"], s["attrs"]["l"], s["attrs"]["w"]) == (
+            16, len(wl.layers), 1)
+
+
+def test_nsga2_generation_spans():
+    from repro.explore.search import nsga2
+    from repro.explore.space import space_for_workload
+    space = space_for_workload("vgg16")
+    res, spans = _traced(lambda: nsga2(space, "vgg16", 24, pop_size=8,
+                                       seed=1, backend="numpy"))
+    by_id, kids = _tree(spans)
+    gens = [s for s in spans if s["name"] == "nsga2.generation"]
+    assert len(gens) == 2
+    for g in gens:
+        assert sorted(_names_under(kids, g)) == sorted(
+            ["nsga2.rank", "nsga2.rank", "nsga2.archive",
+             "nsga2.hypervolume", "explore.evaluate"])
+    evals = [s for s in spans if s["name"] == "explore.evaluate"]
+    assert len(evals) == 3                 # the first population, then
+    for e in evals:                        # one per generation
+        assert _names_under(kids, e).count("explore.objectives") == 1
+
+
+@pytest.mark.parametrize("suite", [("vgg16",), ("vgg16", "resnet34")])
+def test_evaluator_kernel_launch_and_wait(suite, jax_usable):
+    """One Pallas call per evaluation: its launch and the host's wait
+    on its outputs are siblings under explore.evaluate, before the
+    objective matrix."""
+    if not jax_usable:
+        pytest.skip("jax unusable on this host")
+    from repro.explore.search import Evaluator
+    from repro.explore.space import space_for_workload, space_for_workloads
+    space = (space_for_workload(suite[0]) if len(suite) == 1
+             else space_for_workloads(suite))
+    ev = Evaluator(space, suite[0] if len(suite) == 1 else list(suite),
+                   backend="jax", use_pallas=True)
+    g = space.random_population(8, np.random.default_rng(0))
+    _, spans = _traced(lambda: ev.evaluate(g))
+    by_id, kids = _tree(spans)
+    (e,) = [s for s in spans if s["name"] == "explore.evaluate"]
+    names = [n for n in _names_under(kids, e)
+             if n in ("kernel.launch", "kernel.wait", "explore.objectives")]
+    assert names == ["kernel.launch", "kernel.wait", "explore.objectives"]
+    (launch,) = [s for s in spans if s["name"] == "kernel.launch"]
+    assert launch["attrs"]["w"] == len(suite)
