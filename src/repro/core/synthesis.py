@@ -249,6 +249,10 @@ def synthesize_many(configs: Sequence[AcceleratorConfig],
 # streamed sweep driver can hydrate 1M-config spaces in bounded time.
 # ---------------------------------------------------------------------------
 
+_LOAD = 4      # the index is rebuilt before it passes 1/_LOAD full ...
+_SPARSE = 8    # ... into a table of at least _SPARSE slots per row
+
+
 class PersistentSynthesisCache:
     """Digest-keyed synthesis store with npz persistence.
 
@@ -258,16 +262,31 @@ class PersistentSynthesisCache:
 
     ``max_rows`` bounds memory: on overflow the oldest half of the rows is
     dropped and the store compacted (counted in ``evictions``).
+
+    The index is an open-addressing table over the ``(N, 2)`` uint64
+    digest words: home slot = low bits of the first word, linear probing,
+    a whole batch probed per round of array operations.  A slot holds the
+    sequence number of its key's newest row (row ``r`` is sequence number
+    ``_base + r``) and the key's first word.  Compaction only advances
+    ``_base``: the dropped rows' slots go stale, probed past and never
+    matched.  Once a quarter of the table is taken, the rows are indexed
+    afresh under sequence numbers above every old one, which frees every
+    slot below ``_floor`` without a refill.
     """
 
     def __init__(self, path: str | pathlib.Path | None = None,
                  max_rows: int | None = None):
         self.path = pathlib.Path(path) if path is not None else None
         self.max_rows = max_rows
-        self._index: dict[bytes, int] = {}
         self._keys = np.empty((0, 2), dtype=np.uint64)
         self._vals = np.empty((0, len(REPORT_COLUMNS)), dtype=np.float64)
+        self._top = np.empty(0, dtype=bool)   # row is its key's newest
         self._n = 0
+        self._slots = np.empty(0, dtype=np.complex128)
+        self._seq = self._slots.view(np.int64)[::2]
+        self._floor = self._base = 0
+        self._used = 0                      # slots taken, stale included
+        self._live = 0                      # distinct keys with a row
         self.hits = 0
         self.misses = 0
         self.evictions = 0
@@ -297,12 +316,14 @@ class PersistentSynthesisCache:
             return
         keep = self.max_rows // 2           # newest half survives
         drop = self._n - keep
+        self._live -= int(np.count_nonzero(self._top[:drop]))
         self._keys[:keep] = self._keys[drop:self._n]
         self._vals[:keep] = self._vals[drop:self._n]
+        self._top[:keep] = self._top[drop:self._n]
         self._n = keep
+        self._base += drop                  # the dropped rows' slots go stale
         self.evictions += drop
-        buf = np.ascontiguousarray(self._keys[:keep]).tobytes()
-        self._index = {buf[16 * i:16 * (i + 1)]: i for i in range(keep)}
+        obs_metrics.get_registry().inc("synth_cache.evictions", drop)
 
     def __len__(self) -> int:
         return self._n
@@ -314,18 +335,126 @@ class PersistentSynthesisCache:
             cap = max(need, 2 * cap, 1024)
             self._keys = np.resize(self._keys, (cap, 2))
             self._vals = np.resize(self._vals, (cap, len(REPORT_COLUMNS)))
+            self._top = np.resize(self._top, cap)
+
+    def _append(self, u64: np.ndarray, vals: np.ndarray) -> None:
+        """Store rows at the end and index them."""
+        m = len(u64)
+        self._grow(m)
+        self._keys[self._n:self._n + m] = u64
+        self._vals[self._n:self._n + m] = vals
+        self._index(np.arange(self._n, self._n + m), u64)
+        self._n += m
+
+    # -- index -------------------------------------------------------------
+
+    def _reindex(self, extra: int) -> None:
+        """Index the stored rows afresh, with room for ``extra`` more.
+
+        A slot is one 16-byte record (sequence number, first key word),
+        held as one complex128 so that a batch gathers and scatters whole
+        records with 1-D fancy indexing."""
+        size = 1 << max(10, (_SPARSE * (self._n + extra) - 1).bit_length())
+        if size > len(self._slots):
+            self._slots = np.full((size, 2), -1, np.int64) \
+                .view(np.complex128)[:, 0]
+            self._seq = self._slots.view(np.int64)[::2]
+            self._floor = self._base = 0
+        else:
+            # number the rows past every sequence number handed out so
+            # far: each slot of the table reads as free
+            self._floor = self._base = self._base + self._n
+        self._used = self._live = 0
+        self._index(np.arange(self._n), self._keys[:self._n])
+
+    def _words(self, u64: np.ndarray):
+        """First word (as int64), second word and home slot of each key."""
+        k0 = np.ascontiguousarray(u64[:, 0]).view(np.int64)
+        pos = (k0 & (len(self._slots) - 1)).astype(np.intp)
+        return k0, np.ascontiguousarray(u64[:, 1]), pos
+
+    def _probe(self, k0: np.ndarray, k1: np.ndarray, idx: np.ndarray,
+               pos: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """One probe round for the keys ``idx`` at the slots ``pos``:
+        the slots' sequence numbers, and which hold the key itself live
+        (both words compare; a stale or free slot never matches)."""
+        got = self._slots[pos].view(np.int64).reshape(-1, 2)
+        seq = got[:, 0]
+        same = (got[:, 1] == k0[idx]) & (seq >= self._base)
+        cand = np.flatnonzero(same)
+        if len(cand):
+            rows = seq[cand] - self._base
+            key1 = self._keys.view(np.complex128)[rows, 0] \
+                .view(np.uint64)[1::2]
+            same[cand] = key1 == k1[idx[cand]]
+        return seq, same
+
+    def _find(self, u64: np.ndarray) -> np.ndarray:
+        """Row of each key's newest live row, -1 where absent."""
+        rows = np.full(len(u64), -1, dtype=np.intp)
+        if not self._n:
+            return rows                     # no live row, every slot stale
+        wrap = len(self._slots) - 1
+        k0, k1, pos = self._words(u64)
+        idx = np.arange(len(u64))
+        while len(idx):
+            seq, hit = self._probe(k0, k1, idx, pos)
+            rows[idx[hit]] = seq[hit] - self._base
+            on = np.flatnonzero((seq >= self._floor) & ~hit)
+            idx, pos = idx[on], (pos[on] + 1) & wrap
+        return rows
+
+    def _index(self, rows: np.ndarray, u64: np.ndarray) -> None:
+        """Point the index at the stored ``rows``, whose keys are ``u64``.
+
+        A key found live has its slot raised to the newest row; an absent
+        key takes the first free slot on its probe path.  Keys repeated
+        in ``rows`` race for one free slot: one wins it, the rest find it
+        there next round.
+        """
+        if _LOAD * (self._used + len(rows)) > len(self._slots):
+            self._reindex(len(rows))
+        seqs = self._base + rows
+        wrap = len(self._slots) - 1
+        k0, k1, pos = self._words(u64)
+        rec = np.stack([seqs, k0], axis=-1).view(np.complex128)[:, 0]
+        slot = np.empty(len(rows), dtype=np.intp)  # where each row landed
+        idx = np.arange(len(rows))
+        claimed = 0
+        while len(idx):
+            seq, same = self._probe(k0, k1, idx, pos)
+            hit = np.flatnonzero(same)
+            if len(hit):
+                self._top[seq[hit] - self._base] = False
+                np.maximum.at(self._seq, pos[hit], seqs[idx[hit]])
+                slot[idx[hit]] = pos[hit]
+            free = seq < self._floor
+            on = ~free & ~same              # another key, or stale
+            got = np.flatnonzero(free)
+            if len(got):
+                gpos, gidx = pos[got], idx[got]
+                self._slots[gpos] = rec[gidx]
+                slot[gidx] = gpos           # a loser's is set again later
+                won = self._seq[gpos] == seqs[gidx]
+                claimed += int(np.count_nonzero(won))
+                free[got[won]] = False      # losers retry the same slot
+            keep = np.flatnonzero(on | free)
+            idx, pos = idx[keep], (pos[keep] + on[keep]) & wrap
+        self._top[rows] = self._seq[slot] == seqs
+        self._used += claimed
+        self._live += claimed
+
+    # -- batch API ---------------------------------------------------------
 
     def lookup(self, digests) -> tuple[np.ndarray, dict[str, np.ndarray]]:
         """(hit_mask, columns) for a digest batch; missed rows are zero."""
-        keys = digest_keys(digests)
-        rows = np.array([self._index.get(k, -1) for k in keys],
-                        dtype=np.intp)
+        rows = self._find(digests_to_u64(digests))
         mask = rows >= 0
-        vals = np.zeros((len(keys), len(REPORT_COLUMNS)), dtype=np.float64)
+        vals = np.zeros((len(rows), len(REPORT_COLUMNS)), dtype=np.float64)
         if mask.any():
             vals[mask] = self._vals[rows[mask]]
-        nh = int(mask.sum())
-        nm = len(keys) - nh
+        nh = int(np.count_nonzero(mask))
+        nm = len(rows) - nh
         self.hits += nh
         self.misses += nm
         reg = obs_metrics.get_registry()
@@ -335,34 +464,27 @@ class PersistentSynthesisCache:
 
     def insert(self, digests, cols: dict[str, np.ndarray],
                rows_mask: np.ndarray | None = None) -> int:
-        """Store (a masked subset of) a digest batch's columns.
+        """Store (a masked subset of) a digest batch's columns; returns
+        the change in distinct keys held, compaction included.
 
-        Bulk path: rows append en masse and the index updates with one
-        ``dict.update``.  Duplicate keys (re-inserted or repeated within
-        the batch) leave their older rows in place as dead weight and
-        point the index at the newest — values for a given digest are
-        identical by construction, so this only costs bytes, not
-        correctness.
+        Bulk path: rows append en masse and the index takes the batch in
+        rounds of array operations.  Duplicate keys (re-inserted or
+        repeated within the batch) leave their older rows in place as
+        dead weight and point the index at the newest — values for a
+        given digest are identical by construction, so this only costs
+        bytes, not correctness.
         """
-        u64 = np.ascontiguousarray(digests_to_u64(digests))
+        u64 = digests_to_u64(digests)
         vals = np.stack([np.asarray(cols[c], dtype=np.float64)
                          for c in REPORT_COLUMNS], axis=-1)
         if rows_mask is not None:
-            u64, vals = np.ascontiguousarray(u64[rows_mask]), vals[rows_mask]
-        m = len(u64)
-        if m == 0:
+            u64, vals = u64[rows_mask], vals[rows_mask]
+        if len(u64) == 0:
             return 0
-        self._grow(m)
-        self._keys[self._n:self._n + m] = u64
-        self._vals[self._n:self._n + m] = vals
-        buf = u64.tobytes()
-        before = len(self._index)
-        self._index.update(
-            zip((buf[16 * i:16 * (i + 1)] for i in range(m)),
-                range(self._n, self._n + m)))
-        self._n += m
+        before = self._live
+        self._append(u64, vals)
         self._compact()
-        return len(self._index) - before
+        return self._live - before
 
     def save(self, path: str | pathlib.Path | None = None) -> int:
         """Write all rows to ``path`` (default: the constructor path).
@@ -417,12 +539,8 @@ class PersistentSynthesisCache:
             raise ValueError(
                 f"cache snapshot shapes {keys.shape} / {vals.shape} are "
                 f"not (N, 2) / (N, {len(REPORT_COLUMNS)})")
-        self._keys = keys.copy()
-        self._vals = vals.copy()
-        self._n = len(keys)
-        buf = keys.tobytes()
-        self._index = {buf[16 * i:16 * (i + 1)]: i
-                       for i in range(self._n)}
+        self.clear()
+        self._append(keys, vals)            # the last occurrence wins
         self.hits = int(state["hits"])
         self.misses = int(state["misses"])
         self.evictions = int(state["evictions"])
@@ -455,18 +573,13 @@ class PersistentSynthesisCache:
             if not np.isfinite(vals).all():
                 raise ValueError(
                     f"synthesis cache {path}: non-finite report values")
+        # keys already present are skipped; within the file the first
+        # occurrence wins
+        take = np.zeros(len(keys), dtype=bool)
+        take[np.unique(keys, axis=0, return_index=True)[1]] = True
+        take &= self._find(keys) < 0
         before = self._n
-        self._grow(len(keys))
-        buf = keys.tobytes()
-        for i in range(len(keys)):
-            key = buf[16 * i:16 * (i + 1)]
-            if key in self._index:
-                continue
-            row = self._n
-            self._index[key] = row
-            self._keys[row] = keys[i]
-            self._vals[row] = vals[i]
-            self._n += 1
+        self._append(keys[take], vals[take])
         self._compact()
         return self._n - before
 

@@ -354,6 +354,16 @@ def test_sweep_metrics_always_on():
     assert obs.get_tracer().spans() == []      # tracing stayed off
 
 
+def test_synth_cache_evictions_counter():
+    """The registry counts the rows each compaction drops, as the cache
+    itself does."""
+    cache = PersistentSynthesisCache(max_rows=24)
+    _sweep_chunked(get_workload("vgg16"), _space(), backend="numpy",
+                   chunk_size=CHUNK, cache=cache, save_cache=False)
+    assert cache.evictions > 0
+    assert obs.snapshot()["synth_cache.evictions"] == cache.evictions
+
+
 def test_wall_s_flushed_on_injected_failure():
     """Satellite bugfix: a failed attempt still reports its wall time —
     both into the (discarded) timings dict and the metrics registry —
