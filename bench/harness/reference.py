@@ -2,17 +2,18 @@
 
 A float64 numpy restatement of the model the program implements: the PE
 constants, the analytical synthesis oracle with its counter-hash jitter,
-the row-stationary layer mapping and energy model, the tier-0
+the aggregation of a layer table into per-config results, the tier-0
 quantization-noise proxy, and the event-driven serving-fleet simulator.
-It imports nothing of ``repro`` and takes nothing the program made: it
-reads the configuration file (layer shapes, hardware factors) and the
-inputs the benchmark drew from its seed.
+How a network's layers map onto the array, and what each costs, is its
+layer model's (``bench/layers/<model>.py``; the conv mapping is
+``row_stationary``).  It imports nothing of ``repro`` and takes nothing
+the program made: it reads the configuration file (layer shapes,
+hardware factors) and the inputs the benchmark drew from its seed.
 
-Everything is vectorized over configs in plain numpy; the layer table is
-built one layer at a time.  ``prec="bf16"`` rounds every floating
-quantity it produces (synthesis results, per-layer cycles and energies,
-aggregates, the noise table) to bfloat16: the control, one precision below
-the float32 that the configurations state.
+Everything is vectorized over configs in plain numpy.  ``prec="bf16"``
+rounds every floating quantity it produces (synthesis results, per-layer
+cycles and energies, aggregates, the noise table) to bfloat16: the
+control, one precision below the float32 that the configurations state.
 """
 
 from __future__ import annotations
@@ -96,11 +97,13 @@ def _uniform(lane):
 # Hardware: synthesis oracle
 # ---------------------------------------------------------------------------
 
-def _rf_energy(bits):
+def rf_energy(bits):
+    """Energy (pJ) of one access to a register file of ``bits``."""
     return 0.035 * np.sqrt(np.maximum(bits / 8192.0, 0.03125)) + 0.015
 
 
-def _sram_energy(bits):
+def sram_energy(bits):
+    """Energy (pJ) of one access to an SRAM of ``bits``."""
     return 0.09 * np.sqrt(np.maximum(bits / 8192.0, 0.03125)) + 0.04
 
 
@@ -142,88 +145,27 @@ def hardware(pe_type, rows, cols, ifmap, filt, psum, glb_kb, bw_gbps,
 
 
 # ---------------------------------------------------------------------------
-# Workload: row-stationary mapping and energy, one layer at a time
+# Workload: a network through its layer model
 # ---------------------------------------------------------------------------
 
-def _cdiv(a, b):
-    return -(-a // b)
-
-
-def layer_fields(layer) -> dict:
-    """A configuration file's layer row ``[name, h, w, c, k, r, s,
-    stride, batch]`` as integers, with its output size and MACs."""
-    _, h, w, c, k, r, s, stride, batch = layer
-    e = max(1, (h - r) // stride + 1)
-    f = max(1, (w - s) // stride + 1)
-    return dict(h=h, w=w, c=c, k=k, r=r, s=s, e=e, f=f, n=batch,
-                macs=batch * k * c * r * s * e * f)
-
-
-def evaluate(hw: dict, layers, modes: np.ndarray,
+def evaluate(hw: dict, network, modes: np.ndarray,
              prec: str = "f64") -> dict:
     """Aggregates of one network on a config batch.
 
+    ``network`` carries its rows and its layer model (``spec.Network``);
     ``modes`` is the ``(N, L)`` execution mode (PE-type index) of every
     layer on every config.  Returns per-config ``latency_s``,
     ``energy_j``, ``throughput_gmacs`` and ``perf_per_area``."""
-    return aggregate(layer_table(hw, layers, modes), hw, prec)
-
-
-def layer_table(hw: dict, layers, modes: np.ndarray) -> dict:
-    """The per-layer quantities that neither the DRAM bandwidth nor the
-    synthesized clock and area change, each ``(N, L)``: compute cycles,
-    DRAM bytes, and the energy without leakage (pJ); and the network's
-    MACs."""
-    rows, cols, glb_kb = hw["rows"], hw["cols"], hw["glb_kb"]
-    e_spad_pj = _rf_energy(hw["spad_bits"].astype(np.float64))
-    e_glb_pj = _sram_energy(hw["glb_bits"].astype(np.float64))
-    shape = (len(rows), len(layers))
-    tab = {"compute": np.zeros(shape, np.int64),
-           "dram_b": np.zeros(shape, np.int64),
-           "pj": np.zeros(shape)}
-    total_macs = 0
-    for j, layer in enumerate(layers):
-        x = layer_fields(layer)
-        r, s, e, f, c, k, n = (x[v] for v in "r s e f c k n".split())
-        ab, wb = ACT_BITS[modes[:, j]], WEIGHT_BITS[modes[:, j]]
-        sets_fit = np.maximum(1, rows // r)
-        c_sim = np.minimum(c, sets_fit)
-        k_sim = np.maximum(1, sets_fit // c_sim)
-        fit_horz = np.minimum(e, cols)
-        n_e, n_c, n_k = _cdiv(e, fit_horz), _cdiv(c, c_sim), _cdiv(k, k_sim)
-        compute = n * n_e * n_c * n_k * s * f
-        ifmap_el = n * c * x["h"] * x["w"]
-        weight_el = k * c * r * s
-        ofmap_el = n * k * e * f
-        ifmap_b = ifmap_el * ab // 8
-        glb_half = glb_kb * 1024 // 2
-        filt_one = np.maximum(1, c * r * s * wb // 8)
-        n_k_glb = _cdiv(k, np.maximum(1, glb_half // filt_one))
-        restream = np.where(ifmap_b <= glb_half, 1, n_k_glb)
-        dram_b = ifmap_b * restream + weight_el * wb // 8 + ofmap_el * ab // 8
-        dram_el = ifmap_el * restream + weight_el + ofmap_el
-        filt_res = np.maximum(1, hw["filt"] // max(1, s))
-        glb_el = (2 * dram_el + ifmap_el * _cdiv(n_k, filt_res)
-                  + weight_el * np.maximum(1, n_e // np.minimum(n_e, filt_res))
-                  + 2 * ofmap_el * np.maximum(
-                      0, np.where(hw["psum"] >= f, 0, n_c - 1)))
-        macs = x["macs"]
-        tab["compute"][:, j] = compute
-        tab["dram_b"][:, j] = dram_b
-        tab["pj"][:, j] = (macs * MAC_ENERGY_PJ[modes[:, j]]
-                           + 3 * macs * e_spad_pj + glb_el * e_glb_pj)
-        total_macs += macs
-    tab["macs"] = total_macs
-    return tab
+    return aggregate(network.model.table(hw, network.rows, modes), hw, prec)
 
 
 def take(tab: dict, idx: np.ndarray) -> dict:
-    """The rows ``idx`` of a :func:`layer_table`."""
+    """The rows ``idx`` of a layer model's table."""
     return {k: v if k == "macs" else v[idx] for k, v in tab.items()}
 
 
 def aggregate(tab: dict, hw: dict, prec: str = "f64") -> dict:
-    """Per-config aggregates from a :func:`layer_table` and the
+    """Per-config aggregates from a layer model's table and the
     bandwidth, clock, area and leakage of each config: a layer takes the
     longer of its compute and its DRAM transfer, and leaks for as long."""
     q = rounder(prec)
@@ -293,9 +235,10 @@ def noise_table(prec: str = "f64") -> np.ndarray:
     ])
 
 
-def accuracy_noise(modes: np.ndarray, layers, table: np.ndarray):
-    """MAC-weighted noise power of each config's layer modes."""
-    macs = np.array([layer_fields(l)["macs"] for l in layers],
+def accuracy_noise(modes: np.ndarray, network, table: np.ndarray):
+    """MAC-weighted noise power of each config's layer modes, with each
+    layer's MACs from the network's layer model."""
+    macs = np.array(network.model.layer_macs(network.rows),
                     dtype=np.float64)
     return (table[modes] * (macs / macs.sum())).sum(axis=1)
 

@@ -31,9 +31,8 @@ import time
 
 import numpy as np
 
-from harness import reference
+from harness import reference, spec
 from harness.checks import Check, covered, outranked, rel_err
-from harness.stream import program_workload
 
 N_HW_GENES = 5
 NOISE = ("accuracy_noise", "worst_accuracy_noise")
@@ -63,14 +62,14 @@ def draw_arrivals(spec: dict, seed: int):
 
 class Driver:
     def __init__(self, config: dict, traffic: dict, seed: int):
-        self.networks = config["networks"]
+        self.networks = spec.networks(config)
         self.config = config
         self.mix = traffic
         self.serving = traffic["driver"] == "serving"
         self.objectives = tuple(traffic["objectives"])
         self.limits = config["limits"]
         self.seed = seed
-        self.workloads = tuple(program_workload(n) for n in self.networks)
+        self.workloads = tuple(n.program() for n in self.networks)
         self.overrides = dict(
             pe_types=tuple(config["pe_types"]),
             array_dims=tuple(tuple(d) for d in config["array_dims"]),
@@ -152,7 +151,7 @@ class Driver:
     def kernel_calls(self, spans) -> list:
         """Logical ``(n, l, w, mixed)`` of every kernel call traced: one
         per evaluation that found genomes outside the memo."""
-        l = sum(len(n["layers"]) for n in self.networks)
+        l = sum(n.n_layers for n in self.networks)
         return [(s["attrs"]["kernel"], l, len(self.networks), True)
                 for s in spans if s["name"] == "explore.evaluate"
                 and s["attrs"].get("kernel")]
@@ -182,10 +181,10 @@ class Driver:
         table = reference.noise_table(prec)
         per_net, start = [], 0
         for net in self.networks:
-            m = modes[:, start:start + len(net["layers"])]
-            start += len(net["layers"])
-            agg = reference.evaluate(hw, net["layers"], m, prec)
-            agg["noise"] = reference.accuracy_noise(m, net["layers"], table)
+            m = modes[:, start:start + net.n_layers]
+            start += net.n_layers
+            agg = reference.evaluate(hw, net, m, prec)
+            agg["noise"] = reference.accuracy_noise(m, net, table)
             per_net.append(agg)
         noise = np.max([a["noise"] for a in per_net], axis=0)
         cols = {"neg_worst_perf_per_area":

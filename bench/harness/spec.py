@@ -7,11 +7,23 @@
   (``stream``, ``nsga2`` or ``serving``) and its parameters;
 * ``bench/metrics/<metric>.py`` is the reader of one per-layer metric,
   a module with ``read(run) -> float | None``;
+* ``bench/layers/<model>.py`` is a layer model: how the rows of a
+  network that names it (``"layer_model"``; ``row_stationary``, the conv
+  mapping, where the network names none) map onto the array and what
+  each costs.  It exports ``layer_macs(rows)`` (the MACs of each layer on
+  the kernel's layer axis; its length is the network's layer count),
+  ``table(hw, rows, modes)`` (the ``(N, L)`` ``compute`` cycles,
+  ``dram_b`` bytes and ``pj`` energy without leakage, and the network's
+  ``macs``), ``kernel_work(rows)`` (the sweep kernel's operations per
+  config and layer-table bytes for these rows) and
+  ``program_network(network)`` (the program's ``Workload``, the only
+  function of the file that imports the program);
 * ``bench/peaks.json`` holds the published peaks, keyed by the
   ``device_kind`` the chip reports.
 
 Adding a configuration, a mix or a metric is adding a file and an entry
-in ``BENCHMARK.json``; no file here changes.
+in ``BENCHMARK.json``; adding a layer model is adding a file that a
+configuration names.  No file here changes.
 """
 
 from __future__ import annotations
@@ -20,9 +32,11 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import types
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parent
+DEFAULT_LAYER_MODEL = "row_stationary"
 
 
 class SpecError(ValueError):
@@ -66,11 +80,56 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
         raise SpecError(f"workload {name!r} names config {w['config']!r}, "
                         f"which BENCHMARK.json does not list")
     config = _load_json(root / configs[w["config"]]["file"])
+    networks(config, root)          # every layer model has its file
     traffic = load_traffic(w["traffic"], root)
     e2e = tuple(m for m in bench["end_to_end"] if _reported_in(m, name))
     layer = tuple(m for m in bench["per_layer"] if _reported_in(m, name))
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, end_to_end=e2e, per_layer=layer)
+
+
+@dataclasses.dataclass(frozen=True)
+class Network:
+    """One network of a configuration with the layer model that reads its
+    rows; ``n_layers`` is its length on the kernel's layer axis."""
+
+    name: str
+    rows: list
+    model: types.ModuleType
+    n_layers: int
+
+    def program(self):
+        """The program's workload for this network, which has to hold as
+        many layers as the layer model counts."""
+        workload = self.model.program_network(self)
+        if len(workload.layers) != self.n_layers:
+            raise SpecError(f"network {self.name!r}: the program's workload "
+                            f"has {len(workload.layers)} layers, its layer "
+                            f"model counts {self.n_layers}")
+        return workload
+
+
+def load_layer_model(name: str, root: pathlib.Path = ROOT):
+    """The module ``bench/layers/<name>.py``."""
+    path = root / "bench" / "layers" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"no layer model {name!r} at {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_layers_{name}",
+                                                  path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def networks(config: dict, root: pathlib.Path = ROOT) -> tuple[Network, ...]:
+    """The configuration's networks, each with its layer model."""
+    out = []
+    for net in config["networks"]:
+        model = load_layer_model(net.get("layer_model", DEFAULT_LAYER_MODEL),
+                                 root)
+        out.append(Network(net["name"], net["layers"], model,
+                           len(model.layer_macs(net["layers"]))))
+    return tuple(out)
 
 
 def load_traffic(mix: str, root: pathlib.Path = ROOT) -> dict:

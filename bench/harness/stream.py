@@ -28,7 +28,7 @@ import time
 
 import numpy as np
 
-from harness import reference
+from harness import reference, spec
 from harness.checks import (Check, dominated, extra_gap, missed_gap,
                             pareto_rows, rel_err)
 
@@ -48,23 +48,17 @@ def hardware_grid(config: dict) -> dict:
                      "glb_kb"), a.T))
 
 
-def program_workload(network: dict):
-    """The configuration file's network as the program's workload."""
-    from repro.core.workloads import ConvLayer, Workload
-    return Workload(network["name"], tuple(
-        ConvLayer(*row) for row in network["layers"]))
-
-
 class Driver:
     def __init__(self, config: dict, traffic: dict, seed: int):
-        (self.network,) = config["networks"]
+        self.networks = spec.networks(config)
+        (self.network,) = self.networks
         self.grid = hardware_grid(config)
         self.n_hw = len(self.grid["type"])
         self.chunk = int(traffic["chunk_size"])
         self.bw = tuple(config["dram_bw_gbps"])
         self.limits = config["limits"]
         self.seed = seed
-        self.workload = program_workload(self.network)
+        self.workload = self.network.program()
 
     # -- inputs ------------------------------------------------------------
     def _draw(self, stream: int, i: int):
@@ -117,7 +111,7 @@ class Driver:
 
     def kernel_calls(self, spans) -> list:
         """Logical ``(n, l, w, mixed)`` of every kernel call traced."""
-        return [(s["attrs"]["n"], len(self.network["layers"]), 1, False)
+        return [(s["attrs"]["n"], self.network.n_layers, 1, False)
                 for s in spans if s["name"] == "sweep.synthesize"]
 
     def release(self) -> None:
@@ -148,9 +142,8 @@ class Driver:
 
     def _reference(self, h, bw, prec="f64") -> np.ndarray:
         hw = self._hardware(h, bw, prec)
-        modes = np.repeat(hw["type"][:, None], len(self.network["layers"]),
-                          axis=1)
-        out = reference.evaluate(hw, self.network["layers"], modes, prec)
+        modes = np.repeat(hw["type"][:, None], self.network.n_layers, axis=1)
+        out = reference.evaluate(hw, self.network, modes, prec)
         return np.stack([out[m] for m in METRICS], axis=1)
 
     def replay(self, front_h, front_bw) -> tuple[np.ndarray, np.ndarray]:
@@ -164,9 +157,9 @@ class Driver:
         beats is left out, and the layer model evaluates the rest."""
         pts = np.arange(self.n_hw)
         base = self._hardware(pts, np.ones(self.n_hw))
-        modes = np.repeat(base["type"][:, None], len(self.network["layers"]),
+        modes = np.repeat(base["type"][:, None], self.network.n_layers,
                           axis=1)
-        table = reference.layer_table(base, self.network["layers"], modes)
+        table = self.network.model.table(base, self.network.rows, modes)
         least_cycles = table["compute"].sum(axis=1).astype(np.float64)
         static_pj = table["pj"].sum(axis=1)
         front = np.empty((0, 2))

@@ -210,14 +210,17 @@ def self_seconds(spans: list[dict], name: str) -> list[float]:
 class Readout:
     """What a per-layer metric's reader reads: the cell's name, the
     program's spans of the window, the reduced device trace, the logical
-    shape ``(n, l, w, mixed)`` of every sweep-kernel call, and the
-    device's published peaks."""
+    shape ``(n, l, w, mixed)`` of every sweep-kernel call, the device's
+    published peaks, and the networks (``spec.Network``) whose layers
+    every call holds, whose layer models count its work (None: every
+    layer is ``row_stationary``)."""
 
     cell: str
     spans: list
     trace: DeviceTrace
     peaks: dict
     calls: list
+    networks: tuple | None = None
 
 
 def mean_ms(spans: list[dict], name: str, self_time: bool = False):

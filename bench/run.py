@@ -73,7 +73,8 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     if trace:
         readout = tracing.Readout(cell=cell.name, spans=cap.spans,
                                   trace=cap.trace, peaks=peaks,
-                                  calls=driver.kernel_calls(cap.spans))
+                                  calls=driver.kernel_calls(cap.spans),
+                                  networks=driver.networks)
         metrics = {}
         for m in cell.per_layer:
             value = spec.load_reader(m["name"])(readout)
