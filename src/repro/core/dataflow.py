@@ -93,11 +93,18 @@ def map_layer(layer: ConvLayer, cfg: AcceleratorConfig,
     physical quantities — array dims, scratchpad storage, clock, area,
     leakage — stay those of the synthesized hardware.  ``mode=None`` is
     bit-identical to the original homogeneous path.
+
+    A layer of ``g`` groups is g copies of its one-group layer run one
+    after another: the mapping and the GLB residency tests are a copy's,
+    cycles, bytes, elements and dynamic energy are g times a copy's, and
+    the memory cycles are those of the g copies' DRAM bytes together.
+    With ``g = 1`` every quantity is the ungrouped layer's.
     """
     s = cfg.spec
     ms = s if mode is None else pe_spec(mode)
     r, e, f_, ss = layer.r, layer.e, layer.f, layer.s
     c, k, n = layer.c, layer.k, layer.batch
+    g = layer.groups
 
     # ---- spatial mapping ---------------------------------------------------
     sets_fit = max(1, cfg.pe_rows // r)            # PE sets stacked vertically
@@ -109,11 +116,12 @@ def map_layer(layer: ConvLayer, cfg: AcceleratorConfig,
     n_k_groups = math.ceil(k / k_simult)
 
     passes = n * n_e_groups * n_c_groups * n_k_groups
-    compute_cycles = passes * ss * f_
+    compute_cycles = g * (passes * ss * f_)
     macs = layer.macs
+    macs_one = macs // g
     utilization = macs / max(1, compute_cycles * cfg.num_pes)
 
-    # ---- element / byte counts (quantization-aware) -------------------------
+    # ---- element / byte counts of one group (quantization-aware) -----------
     ab, wb = ms.act_bits, ms.weight_bits
     ifmap_elems = n * c * layer.h * layer.w
     weight_elems = k * c * r * ss
@@ -132,7 +140,7 @@ def map_layer(layer: ConvLayer, cfg: AcceleratorConfig,
     n_k_glb = math.ceil(k / k_fit_glb)
     ifmap_resident = ifmap_bytes <= glb_half
     ifmap_dram = ifmap_bytes * (1 if ifmap_resident else n_k_glb)
-    dram_bytes = ifmap_dram + weight_bytes + ofmap_bytes
+    dram_bytes = g * (ifmap_dram + weight_bytes + ofmap_bytes)
 
     # GLB traffic in *elements* (the GLB port is fixed-width; see pe.py):
     # fills/drains mirror the DRAM stream, the ifmap is multicast-read once
@@ -152,7 +160,7 @@ def map_layer(layer: ConvLayer, cfg: AcceleratorConfig,
     spill = 0 if cfg.psum_spad >= psum_strip else (n_c_groups - 1)
     glb_psum = 2 * ofmap_elems * max(0, spill)
     glb_elems = 2 * dram_elems + glb_ifmap + glb_weight + glb_psum
-    glb_bytes = glb_elems * ab // 8  # reported for reference
+    glb_bytes = g * glb_elems * ab // 8  # reported for reference
 
     # ---- stalls -------------------------------------------------------------
     bw_bytes_per_cycle = cfg.dram_bw_gbps / clock_ghz
@@ -166,11 +174,11 @@ def map_layer(layer: ConvLayer, cfg: AcceleratorConfig,
     # ifmap read + weight read + ~1 psum spad access per MAC (the running
     # sum lives in a register; the spad is touched on row hand-off).
     spad_accesses = 3 * macs
-    e_spad = spad_accesses * rf_access_energy_pj(spad_bits)
-    e_mac = macs * ms.mac_energy_pj
+    e_spad = 3 * macs_one * rf_access_energy_pj(spad_bits)
+    e_mac = macs_one * ms.mac_energy_pj
     e_glb = glb_elems * sram_access_energy_pj(cfg.glb_bits)
     e_leak = leakage_mw * 1e-3 * (total_cycles / (clock_ghz * 1e9)) * 1e12
-    energy_pj = e_mac + e_spad + e_glb + e_leak
+    energy_pj = g * (e_mac + e_spad + e_glb) + e_leak
 
     return LayerResult(
         name=layer.name, macs=macs,

@@ -80,6 +80,7 @@ class WorkloadBatch:
             "h": np.array([l.h for l in ls], dtype=i8),
             "w": np.array([l.w for l in ls], dtype=i8),
             "batch": np.array([l.batch for l in ls], dtype=i8),
+            "groups": np.array([l.groups for l in ls], dtype=i8),
             "macs": np.array([l.macs for l in ls], dtype=i8),
         }
         return cls(name=wl.name, layer_names=tuple(l.name for l in ls),
@@ -166,14 +167,21 @@ def _sweep_kernel(xp, cfg: dict, lay: dict, *, exact: bool = True,
     bit-identical to the per-config-scalar path.
 
     ``outputs="aggregates"`` returns only :data:`AGGREGATE_OUTPUTS`.
+
+    Grouped layers (``lay["groups"]``) follow ``map_layer``: the mapping
+    and byte block is one group's, cycles, DRAM bytes and dynamic energy
+    are scaled by the group count, and ``macs`` is the whole layer's.
     """
     f = np.float64 if exact else np.float32
     r, e, f_, ss = lay["r"], lay["e"], lay["f"], lay["s"]
     c, k, n = lay["c"], lay["k"], lay["batch"]
+    g = lay["groups"]
     macs = lay["macs"]          # int64 when exact, float32 otherwise
 
     def fl(x):                  # promote a (possibly int) array to f
         return x.astype(f)
+
+    macs_one = macs // g if exact else macs / fl(g)
 
     # The mapping / byte-count / GLB-traffic block depends on the config
     # only through (pe_rows, pe_cols, act_bits, weight_bits, glb_kb,
@@ -214,12 +222,12 @@ def _sweep_kernel(xp, cfg: dict, lay: dict, *, exact: bool = True,
         passes = n * n_e_groups * n_c_groups * n_k_groups
         # int multiply is associative: fold the (1, L) factors first so
         # only one product runs per row — value identical to map_layer
-        compute_cycles = passes * (ss * f_)
+        compute_cycles = passes * (g * ss * f_)
         utilization = macs / xp.maximum(1, compute_cycles * cb["num_pes"])
     else:
         # group products can pass 2**31 — promote the accumulator only
         compute_cycles = (fl(n) * fl(n_e_groups) * fl(n_c_groups)
-                          * fl(n_k_groups) * fl(ss) * fl(f_))
+                          * fl(n_k_groups) * fl(ss) * fl(f_) * fl(g))
         utilization = macs / xp.maximum(
             f(1.0), compute_cycles * fl(cb["num_pes"]))
 
@@ -245,14 +253,14 @@ def _sweep_kernel(xp, cfg: dict, lay: dict, *, exact: bool = True,
     if exact:
         ifmap_restream = xp.where(ifmap_bytes <= glb_half, 1, n_k_glb)
         ifmap_dram = ifmap_bytes * ifmap_restream
-        dram_bytes = ifmap_dram + weight_bytes + ofmap_bytes
+        dram_bytes = g * (ifmap_dram + weight_bytes + ofmap_bytes)
         dram_elems = ifmap_elems * ifmap_restream + weight_elems \
             + ofmap_elems
     else:
         ifmap_restream = xp.where(ifmap_bytes <= fl(glb_half),
                                   f(1.0), fl(n_k_glb))
-        dram_bytes = ifmap_bytes * ifmap_restream + weight_bytes \
-            + ofmap_bytes
+        dram_bytes = fl(g) * (ifmap_bytes * ifmap_restream + weight_bytes
+                              + ofmap_bytes)
         dram_elems = fl(ifmap_elems) * ifmap_restream + fl(weight_elems) \
             + fl(ofmap_elems)
 
@@ -268,13 +276,13 @@ def _sweep_kernel(xp, cfg: dict, lay: dict, *, exact: bool = True,
         glb_weight = weight_elems * xp.maximum(1, n_e_groups // w_res)
         glb_psum = 2 * ofmap_elems * xp.maximum(0, spill)
         glb_elems = 2 * dram_elems + glb_ifmap + glb_weight + glb_psum
-        glb_bytes = glb_elems * ab // 8
+        glb_bytes = g * glb_elems * ab // 8
     else:
         glb_ifmap = fl(ifmap_elems) * fl(_ceil_div(n_k_groups, k_res))
         glb_weight = fl(weight_elems) * fl(xp.maximum(1, n_e_groups // w_res))
         glb_psum = 2.0 * fl(ofmap_elems) * fl(xp.maximum(0, spill))
         glb_elems = 2.0 * dram_elems + glb_ifmap + glb_weight + glb_psum
-        glb_bytes = xp.floor(glb_elems * fl(ab) / 8.0)
+        glb_bytes = xp.floor(fl(g) * glb_elems * fl(ab) / 8.0)
 
     if inv is not None:                     # scatter back to all N configs
         compute_cycles = compute_cycles[inv]
@@ -301,13 +309,13 @@ def _sweep_kernel(xp, cfg: dict, lay: dict, *, exact: bool = True,
     # the batch (and trace under jax.jit) — single source for the constants
     e_spad_pj = rf_access_energy_pj(cfg["spad_bits"], xp=xp)
     spad_accesses = 3 * macs
-    e_spad = spad_accesses * e_spad_pj
-    e_mac = macs * cfg["mac_energy_pj"]
+    e_spad = 3 * macs_one * e_spad_pj
+    e_mac = macs_one * cfg["mac_energy_pj"]
     e_glb_pj = sram_access_energy_pj(cfg["glb_bits"], xp=xp)
     e_glb = glb_elems * e_glb_pj
     e_leak = cfg["leak_mw"] * 1e-3 \
         * (total_cycles / (clock_ghz * 1e9)) * 1e12
-    energy_pj = e_mac + e_spad + e_glb + e_leak
+    energy_pj = fl(g) * (e_mac + e_spad + e_glb) + e_leak
 
     if outputs == "layer_totals":
         # the segmented multi-workload reduction happens in the caller
@@ -438,7 +446,7 @@ _JAX_KERNELS: dict = {}
 _CFG_INT32 = ("pe_rows", "pe_cols", "ifmap_spad", "filter_spad",
               "psum_spad", "glb_kb", "glb_bits", "num_pes", "act_bits",
               "weight_bits", "spad_bits")
-_LAY_INT32 = ("r", "s", "e", "f", "c", "k", "h", "w", "batch")
+_LAY_INT32 = ("r", "s", "e", "f", "c", "k", "h", "w", "batch", "groups")
 
 
 def _to_jax_inputs(cfg: dict, lay: dict, exact: bool) -> tuple[dict, dict]:
@@ -1619,6 +1627,7 @@ def _sweep_chunked(workload: Workload,
                 pending.append((soa, n, cfg, lay, finalize, backend,
                                 save_info, cache_state, ci))
                 _reg.observe("sweep.inflight", len(pending))
+                _reg.inc("sweep.layer_pairs", n * len(wb))
                 # bounded prefetch: drain FIFO until at most depth-1
                 # chunks stay in flight behind the next synthesis
                 while len(pending) >= depth:

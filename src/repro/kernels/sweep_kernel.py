@@ -55,7 +55,8 @@ CFG_FIELDS = ("pe_rows", "pe_cols", "num_pes", "act_bits", "weight_bits",
               "glb_kb", "glb_bits", "filter_spad", "psum_spad",
               "spad_bits", "dram_bw_gbps", "mac_energy_pj", "clock_ghz",
               "area_mm2", "leak_mw")
-LAY_FIELDS = ("r", "s", "e", "f", "c", "k", "h", "w", "batch", "macs")
+LAY_FIELDS = ("r", "s", "e", "f", "c", "k", "h", "w", "batch", "groups",
+              "macs")
 # the per-layer precision columns that may be (N, L) instead of (N, 1)
 MIXED_CFG_FIELDS = ("act_bits", "weight_bits", "mac_energy_pj")
 # TPU vector lane width: the layer-axis tile of the compiled kernel
@@ -262,10 +263,11 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
             f"sweep_aggregates_pallas: block sizes must be >= 1, got "
             f"block_n={block_n}, block_l={block_l}")
 
-    with obs_trace.span("kernel.launch", n=n, l=l, w=w):
+    n_pad = _ceil_to(n, block_n)
+    l_pad = _ceil_to(l, block_l)
+    with obs_trace.span("kernel.launch", n=n, l=l, w=w,
+                        tiles=l_pad // block_l):
         jcfg, jlay = _to_jax_inputs(cfg, lay, exact=False)
-        n_pad = _ceil_to(n, block_n)
-        l_pad = _ceil_to(l, block_l)
 
         operands = [_pad_cfg(np.asarray(jcfg[name]), n_pad, l_pad)
                     for name in CFG_FIELDS]

@@ -101,3 +101,18 @@ def test_sweep_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     out, = jax.tree.leaves(compiled.out_info)
     assert out.shape == (n_pad, 6 * w)
     assert np.dtype(out.dtype) == np.float32
+
+
+def test_sweep_kernel_compiles_for_v5e_over_three_layer_tiles(
+        one_chip, no_persistent_cache):
+    """A Moonlight-16B-A3B decode step (322 grouped GEMM rows) in a
+    32,768-config stream chunk: three 128-wide layer tiles."""
+    n, l, w = 32768, 322, 1
+    block_n, block_l = default_tiling(n, l)
+    n_pad, l_pad = _ceil_to(n, block_n), _ceil_to(l, block_l)
+    assert l_pad // block_l == 3
+    fn = _build_sweep_call(n_pad, l_pad, w, block_n, block_l,
+                           (False,) * len(MIXED_CFG_FIELDS), False)
+    compiled = fn.lower(*_operands(n_pad, l_pad, w, False,
+                                   one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
