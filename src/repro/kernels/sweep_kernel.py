@@ -45,8 +45,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.dse_batch import (AGGREGATE_OUTPUTS, _jax_has_accelerator,
-                                  _sweep_kernel, _to_jax_inputs)
+from repro.core.dse_batch import (_CFG_INT32, _LAY_INT32, AGGREGATE_OUTPUTS,
+                                  _jax_has_accelerator, _sweep_kernel)
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 # operand order of the pallas_call — every cfg/lay field the mapping +
@@ -146,12 +147,12 @@ def _sweep_block_body(*refs, n_l: int, block_l: int, w: int):
              perf_per_area], axis=1)
 
 
-@functools.lru_cache(maxsize=64)
 def _build_sweep_call(n_pad: int, l_pad: int, w: int, block_n: int,
                       block_l: int, mixed_wide: tuple[bool, ...],
                       interpret: bool):
-    """Compiled pallas_call for one (shape, tiling, mode) signature —
-    cached so a steady-state chunk stream traces exactly once."""
+    """The pallas_call for one (shape, tiling, mode) signature: 15 config
+    operands, 11 layer rows, the ``(l_pad, w)`` segment mask and the
+    ``(1, w)`` segment MACs in, one ``(n_pad, 6 * w)`` block out."""
     n_l = l_pad // block_l
     wide = dict(zip(MIXED_CFG_FIELDS, mixed_wide))
 
@@ -164,7 +165,7 @@ def _build_sweep_call(n_pad: int, l_pad: int, w: int, block_n: int,
     in_specs.append(pl.BlockSpec((block_l, w), lambda i, l: (l, 0)))
     in_specs.append(pl.BlockSpec((1, w), lambda i, l: (0, 0)))
 
-    call = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_sweep_block_body, n_l=n_l, block_l=block_l,
                           w=w),
         grid=(n_pad // block_n, n_l),
@@ -175,25 +176,115 @@ def _build_sweep_call(n_pad: int, l_pad: int, w: int, block_n: int,
                         for _ in range(4)],
         interpret=interpret,
     )
-    return jax.jit(call)
 
 
-def _pad_cfg(a: np.ndarray, n_pad: int, l_pad: int) -> np.ndarray:
-    n, width = a.shape
-    if n_pad > n:       # repeat the last row: valid throwaway work
-        a = np.concatenate([a, np.repeat(a[-1:], n_pad - n, axis=0)])
-    if width > 1 and l_pad > width:
-        a = np.concatenate(
-            [a, np.repeat(a[:, :1], l_pad - width, axis=1)], axis=1)
-    return a
+# The host hands the program one int32 buffer: every operand but the
+# segment mask, flattened row-major one after another, float32 fields
+# bit-cast into it.  Each host-to-device transfer and each program launch
+# has a fixed cost that dwarfs a chunk's ~2 MB, so one buffer in and one
+# program per call.
+_INT32_FIELDS = frozenset(_CFG_INT32) | frozenset(_LAY_INT32)
 
 
-def _pad_lay(a: np.ndarray, l_pad: int) -> np.ndarray:
-    width = a.shape[1]
-    if l_pad > width:   # repeat layer 0: masked out of every segment
-        a = np.concatenate(
-            [a, np.repeat(a[:, :1], l_pad - width, axis=1)], axis=1)
-    return a
+@functools.lru_cache(maxsize=64)
+def _packed_layout(n_pad: int, l_pad: int, w: int,
+                   mixed_wide: tuple[bool, ...]):
+    """``((name, offset, shape), ...)`` of each packed operand, in
+    pallas_call order with ``"seg_macs"`` last, and the buffer length."""
+    wide = dict(zip(MIXED_CFG_FIELDS, mixed_wide))
+    shapes = [(name, (n_pad, l_pad if wide.get(name, False) else 1))
+              for name in CFG_FIELDS]
+    shapes += [(name, (1, l_pad)) for name in LAY_FIELDS]
+    shapes.append(("seg_macs", (1, w)))
+    layout, off = [], 0
+    for name, shape in shapes:
+        layout.append((name, off, shape))
+        off += shape[0] * shape[1]
+    return tuple(layout), off
+
+
+def _pack_operands(cfg: dict, lay: dict, n: int, l: int,
+                   bounds: tuple[tuple[int, int], ...], layout,
+                   size: int) -> np.ndarray:
+    """Cast each field to int32 / float32 as it is copied into one
+    buffer.  Padded config rows repeat the last row (valid throwaway
+    work); padded layer columns repeat layer 0 (masked out of every
+    segment)."""
+    buf = np.empty(size, dtype=np.int32)
+    fbuf = buf.view(np.float32)
+    n_cfg = len(CFG_FIELDS)
+    for name, off, (rows, width) in layout[:n_cfg]:
+        dst = (buf if name in _INT32_FIELDS else fbuf)[
+            off:off + rows * width].reshape(rows, width)
+        src = cfg[name]
+        dst[:n, :src.shape[1]] = src
+        if width > src.shape[1]:
+            dst[:n, src.shape[1]:] = dst[:n, :1]
+        if rows > n:
+            dst[n:] = dst[n - 1]
+    for name, off, (_, width) in layout[n_cfg:-1]:
+        dst = (buf if name in _INT32_FIELDS else fbuf)[off:off + width]
+        dst[:l] = lay[name][0]
+        dst[l:] = dst[0]
+    offsets = {name: off for name, off, _ in layout}
+    macs = fbuf[offsets["macs"]:offsets["macs"] + l]
+    seg = offsets["seg_macs"]
+    fbuf[seg:seg + len(bounds)] = [macs[s:e].sum(dtype=np.float32)
+                                   for s, e in bounds]
+    return buf
+
+
+def _segment_mask(bounds: tuple[tuple[int, int], ...],
+                  l_pad: int) -> np.ndarray:
+    """``(l_pad, w)``: 1 where layer ``j`` belongs to segment ``w``."""
+    mask = np.zeros((l_pad, len(bounds)), dtype=np.float32)
+    for wi, (s, e) in enumerate(bounds):
+        mask[s:e, wi] = 1.0
+    return mask
+
+
+def _unpack_operands(buf, layout, bounds: tuple[tuple[int, int], ...],
+                     l_pad: int) -> list:
+    """The pallas_call's operands from the packed buffer (traced inside
+    the program): XLA slices and reshapes, float32 fields bit-cast back,
+    the segment mask a constant of the program."""
+    ops = []
+    for name, off, shape in layout:
+        x = jax.lax.slice(buf, (off,), (off + shape[0] * shape[1],))
+        x = x.reshape(shape)
+        if name not in _INT32_FIELDS:
+            x = jax.lax.bitcast_convert_type(x, jnp.float32)
+        ops.append(x)
+    seg_macs = ops.pop()
+    return ops + [jnp.asarray(_segment_mask(bounds, l_pad)), seg_macs]
+
+
+@functools.lru_cache(maxsize=64)
+def _build_packed_call(n: int, n_pad: int, l_pad: int,
+                       bounds: tuple[tuple[int, int], ...], block_n: int,
+                       block_l: int, mixed_wide: tuple[bool, ...],
+                       squeeze: bool, interpret: bool):
+    """One jitted program per signature — cached so a steady-state chunk
+    stream traces exactly once: unpack the buffer, run the pallas_call,
+    return the six aggregate columns sliced to ``n``, ``(N,)`` when
+    ``squeeze`` else ``(W, N)``."""
+    w = len(bounds)
+    layout, _ = _packed_layout(n_pad, l_pad, w, mixed_wide)
+    call = _build_sweep_call(n_pad, l_pad, w, block_n, block_l,
+                             mixed_wide, interpret)
+
+    # XLA names the Pallas call's instruction after the jitted function;
+    # profiles, and the benchmark's kernel readers, know the kernel's
+    # device op as tpu_custom_call
+    def tpu_custom_call(buf):
+        out = call(*_unpack_operands(buf, layout, bounds, l_pad))
+        result = {}
+        for idx, name in enumerate(AGGREGATE_OUTPUTS):
+            block = out[:n, idx * w:(idx + 1) * w]     # (N, W)
+            result[name] = block[:, 0] if squeeze else block.T
+        return result
+
+    return jax.jit(tpu_custom_call)
 
 
 def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
@@ -204,9 +295,13 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
     """Aggregate sweep columns via the Pallas kernel.
 
     ``cfg`` / ``lay`` are the float64/int64 arrays of
-    :func:`repro.core.dse_batch._make_cfg_lay` (the x64-free conversion
-    happens here).  ``bounds=None`` treats the whole layer axis as one
-    workload and returns ``{column: (N,)}`` like
+    :func:`repro.core.dse_batch._make_cfg_lay`.  Each field is cast to
+    int32 / float32 (the x64-free policy) as it is copied, padded, into
+    one packed host buffer; one jitted program per signature unpacks it
+    on the device, runs the kernel and slices the outputs, so a call is
+    one host-to-device buffer and one program launch.  ``bounds=None``
+    treats the whole layer axis as one workload and returns
+    ``{column: (N,)}`` like
     ``_run_kernel(..., outputs="aggregates")``; explicit ``bounds``
     returns ``{column: (W, N)}`` like ``_sweep_mixed_many``.  Results are
     jax arrays (dispatch is async under jit) — ``np.asarray`` to
@@ -265,31 +360,15 @@ def sweep_aggregates_pallas(cfg: dict, lay: dict, *,
 
     n_pad = _ceil_to(n, block_n)
     l_pad = _ceil_to(l, block_l)
+    mixed_wide = tuple(np.shape(cfg[name])[1] == l and l > 1
+                       for name in MIXED_CFG_FIELDS)
+    layout, size = _packed_layout(n_pad, l_pad, w, mixed_wide)
     with obs_trace.span("kernel.launch", n=n, l=l, w=w,
-                        tiles=l_pad // block_l):
-        jcfg, jlay = _to_jax_inputs(cfg, lay, exact=False)
-
-        operands = [_pad_cfg(np.asarray(jcfg[name]), n_pad, l_pad)
-                    for name in CFG_FIELDS]
-        operands += [_pad_lay(np.asarray(jlay[name]), l_pad)
-                     for name in LAY_FIELDS]
-        seg_mask = np.zeros((l_pad, w), dtype=np.float32)
-        for wi, (s, e) in enumerate(bounds):
-            seg_mask[s:e, wi] = 1.0
-        seg_macs = np.array(
-            [[jlay["macs"][0, s:e].sum(dtype=np.float32)
-              for s, e in bounds]],
-            dtype=np.float32)
-        operands += [seg_mask, seg_macs]
-
-        mixed_wide = tuple(np.shape(cfg[name])[1] == l and l > 1
-                           for name in MIXED_CFG_FIELDS)
-        fn = _build_sweep_call(n_pad, l_pad, w, block_n, block_l,
-                               mixed_wide, interpret)
-        out = fn(*operands)                # (n_pad, 6 * w), async
-
-        result = {}
-        for idx, name in enumerate(AGGREGATE_OUTPUTS):
-            block = out[:n, idx * w:(idx + 1) * w]     # (N, W)
-            result[name] = block[:, 0] if squeeze else block.T
+                        tiles=l_pad // block_l, buffers=1,
+                        bytes=4 * size):
+        buf = _pack_operands(cfg, lay, n, l, bounds, layout, size)
+        fn = _build_packed_call(n, n_pad, l_pad, bounds, block_n, block_l,
+                                mixed_wide, squeeze, interpret)
+        result = fn(buf)                   # async
+    obs_metrics.get_registry().inc("kernel.h2d_buffers")
     return result

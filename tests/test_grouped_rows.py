@@ -309,7 +309,8 @@ def test_the_decode_step_streams_through_the_pallas_path(jax_usable):
                                    want.front_metrics[k][order], rtol=RTOL)
     launches = [s for s in spans if s["name"] == "kernel.launch"]
     assert len(launches) == 3
-    assert all((s["attrs"]["l"], s["attrs"]["tiles"]) == (22, 1)
+    assert all((s["attrs"]["l"], s["attrs"]["tiles"], s["attrs"]["buffers"],
+                s["attrs"]["bytes"]) == (22, 1, 1, 4 * (15 * 16 + 11 * 22 + 1))
                for s in launches)
     assert pairs == 48 * 22
 

@@ -549,10 +549,14 @@ def test_synthesis_phases_nest_under_sweep_synthesize(path, jax_usable):
         assert launches == []
         return
     assert len(launches) == res.n_chunks
+    l = len(wl.layers)
     for s in launches:
         assert by_id[s["parent_id"]]["name"] == "sweep.dispatch"
         assert (s["attrs"]["n"], s["attrs"]["l"], s["attrs"]["w"]) == (
-            16, len(wl.layers), 1)
+            16, l, 1)
+        # one packed buffer: 15 config columns, 11 layer rows, 1 MAC sum
+        assert (s["attrs"]["buffers"], s["attrs"]["bytes"]) == (
+            1, 4 * (15 * 16 + 11 * l + 1))
 
 
 def test_nsga2_generation_spans():

@@ -17,13 +17,19 @@ pytest.importorskip("jax")
 
 from repro.core.accelerator import AcceleratorConfig, configs_to_soa
 from repro.core.dse_batch import (AGGREGATE_OUTPUTS, _make_cfg_lay,
+                                  _to_jax_inputs,
                                   _sweep_chunked, _sweep_kernel,
                                   _sweep_mixed, _workload_batch,
                                   mixed_assign_cfg, resolve_use_pallas)
 from repro.core.pe import PEType
 from repro.core.synthesis import synthesize_soa
 from repro.core.workloads import get_workload
-from repro.kernels.sweep_kernel import (resolve_pallas_interpret,
+from repro.kernels.sweep_kernel import (CFG_FIELDS, LAY_FIELDS,
+                                        MIXED_CFG_FIELDS, _build_packed_call,
+                                        _build_sweep_call, _ceil_to,
+                                        _pack_operands, _packed_layout,
+                                        _unpack_operands, default_tiling,
+                                        resolve_pallas_interpret,
                                         sweep_aggregates_pallas)
 
 RTOL = 1e-6
@@ -148,6 +154,126 @@ def test_committed_stream_slice_parity():
     want = {k: np.asarray(v, dtype=np.float64) for k, v in
             _sweep_kernel(np, cfg, lay, outputs="aggregates").items()}
     _assert_close(got, want)
+
+
+# ---------------------------------------------------------------------------
+# the packed launch: one host buffer, one program, the kernel's bits
+# ---------------------------------------------------------------------------
+
+def _pad_cfg(a, n_pad, l_pad):
+    """Reference padding of one config operand, field by field."""
+    n, width = a.shape
+    if n_pad > n:
+        a = np.concatenate([a, np.repeat(a[-1:], n_pad - n, axis=0)])
+    if width > 1 and l_pad > width:
+        a = np.concatenate(
+            [a, np.repeat(a[:, :1], l_pad - width, axis=1)], axis=1)
+    return a
+
+
+def _pad_lay(a, l_pad):
+    width = a.shape[1]
+    if l_pad > width:
+        a = np.concatenate(
+            [a, np.repeat(a[:, :1], l_pad - width, axis=1)], axis=1)
+    return a
+
+
+def _field_operands(cfg, lay, bounds, n_pad, l_pad):
+    """The kernel's operands built one field at a time: cast, pad, then
+    the segment mask and the float32 segment MAC sums."""
+    jcfg, jlay = _to_jax_inputs(cfg, lay, exact=False)
+    ops = [_pad_cfg(np.asarray(jcfg[k]), n_pad, l_pad) for k in CFG_FIELDS]
+    ops += [_pad_lay(np.asarray(jlay[k]), l_pad) for k in LAY_FIELDS]
+    mask = np.zeros((l_pad, len(bounds)), dtype=np.float32)
+    for wi, (s, e) in enumerate(bounds):
+        mask[s:e, wi] = 1.0
+    macs = np.array([[jlay["macs"][0, s:e].sum(dtype=np.float32)
+                      for s, e in bounds]], dtype=np.float32)
+    return ops + [mask, macs]
+
+
+def _packed_case(name):
+    rng = np.random.default_rng(13)
+    if name == "one-segment-padded-rows":
+        cfg, lay, _ = _cfg_lay(1000, seed=6)
+        return cfg, lay, None
+    if name == "three-segments-wide":
+        cfg, lay, bounds = _cfg_lay(
+            64, workloads=("vgg16", "resnet34", "resnet50"), seed=7)
+        assign = rng.integers(0, len(tuple(PEType)),
+                              size=(64, lay["r"].shape[1]))
+        return mixed_assign_cfg(cfg, assign), lay, bounds
+    if name == "two-layer-tiles":
+        cfg, lay, _ = _cfg_lay(40, workloads=("resnet50",) * 4, seed=8)
+        return cfg, {k: v[:, :200] for k, v in lay.items()}, None
+    cfg, lay, _ = _cfg_lay(1, seed=9)                   # one config
+    return cfg, lay, None
+
+
+@pytest.mark.parametrize("case", ["one-segment-padded-rows",
+                                  "three-segments-wide", "two-layer-tiles",
+                                  "one-config"])
+def test_packed_launch_is_bit_exact(case):
+    """The program unpacks, bit for bit, the operands the kernel took one
+    field at a time, and returns the raw pallas_call's outputs exactly."""
+    import jax
+    cfg, lay, bounds = _packed_case(case)
+    n, l = cfg["pe_rows"].shape[0], lay["r"].shape[1]
+    segs = bounds or ((0, l),)
+    w = len(segs)
+    block_n, block_l = default_tiling(n, l)
+    n_pad, l_pad = _ceil_to(n, block_n), _ceil_to(l, block_l)
+    mixed_wide = tuple(cfg[k].shape[1] == l and l > 1
+                       for k in MIXED_CFG_FIELDS)
+    layout, size = _packed_layout(n_pad, l_pad, w, mixed_wide)
+    want_ops = _field_operands(cfg, lay, segs, n_pad, l_pad)
+
+    buf = _pack_operands(cfg, lay, n, l, segs, layout, size)
+    got_ops = jax.jit(lambda b: _unpack_operands(b, layout, segs, l_pad))(
+        buf)
+    assert len(got_ops) == len(want_ops)
+    for got, want in zip(got_ops, want_ops):
+        got = np.asarray(got)
+        assert (got.dtype, got.shape) == (want.dtype, want.shape)
+        assert np.array_equal(got.view(np.int32), want.view(np.int32))
+
+    raw = np.asarray(jax.jit(_build_sweep_call(
+        n_pad, l_pad, w, block_n, block_l, mixed_wide, True))(*want_ops))
+    got = sweep_aggregates_pallas(cfg, lay, bounds=bounds, interpret=True)
+    for idx, k in enumerate(AGGREGATE_OUTPUTS):
+        block = raw[:n, idx * w:(idx + 1) * w]
+        want = block[:, 0] if bounds is None else block.T
+        assert np.array_equal(np.asarray(got[k]), want), k
+
+
+def test_packed_launch_counts_one_buffer_and_does_not_retrace():
+    """One call: one kernel.launch span naming one host buffer and its
+    bytes, and the same count on kernel.h2d_buffers.  A second call of
+    the same shape reuses the program."""
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as obs_trace
+    cfg, lay, bounds = _cfg_lay(21, workloads=("vgg16", "resnet34"), seed=3)
+    sweep_aggregates_pallas(cfg, lay, bounds=bounds, interpret=True)
+    n, l = 21, lay["r"].shape[1]
+    fn = _build_packed_call(n, 24, l, bounds, 24, l, (False,) * 3, False,
+                            True)
+    traces, misses = fn._cache_size(), _build_packed_call.cache_info().misses
+    before = obs_metrics.snapshot().get("kernel.h2d_buffers", 0)
+    obs_trace.configure(enabled=True, reset=True)
+    try:
+        sweep_aggregates_pallas(cfg, lay, bounds=bounds, interpret=True)
+        spans = [s.as_dict() for s in obs_trace.get_tracer().spans()]
+    finally:
+        obs_trace.disable()
+        obs_trace.configure(enabled=False, reset=True)
+    launch, = [s for s in spans if s["name"] == "kernel.launch"]
+    assert launch["attrs"]["buffers"] == 1
+    assert launch["attrs"]["bytes"] == 4 * (15 * 24 + 11 * l + 2)
+    after = obs_metrics.snapshot()["kernel.h2d_buffers"]
+    assert after - before == launch["attrs"]["buffers"]
+    assert fn._cache_size() == traces == 1
+    assert _build_packed_call.cache_info().misses == misses
 
 
 # ---------------------------------------------------------------------------

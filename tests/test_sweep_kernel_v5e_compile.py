@@ -1,11 +1,13 @@
 """The Pallas sweep kernel compiles for a TPU v5e at the main path's
 shapes.
 
-Nothing runs: each test lowers ``_build_sweep_call(..., interpret=False)``
-from ``ShapeDtypeStruct``s placed on one chip of a described (not
-attached) ``v5e:2x2`` topology and compiles it, which raises whatever
-the chip's compiler would refuse (block shapes off the (8, 128) tiling,
-scoped-VMEM overruns).  The shapes are those ``chip_smoke.py`` drives:
+Nothing runs: each test lowers the packed program
+``_build_packed_call(..., interpret=False)`` (unpack, the Pallas call,
+the output slices) from a ``ShapeDtypeStruct`` of its one packed buffer,
+placed on one chip of a described (not attached) ``v5e:2x2`` topology,
+and compiles it, which raises whatever the chip's compiler would refuse
+(block shapes off the (8, 128) tiling, scoped-VMEM overruns).  The
+shapes are those ``chip_smoke.py`` drives:
 
 * vgg16 (16 layers), one workload, per-layer precision columns, one
   64-genome search batch (the serving search);
@@ -18,6 +20,7 @@ process at a time may load the TPU compiler library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -25,11 +28,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.dse_batch import _CFG_INT32, _LAY_INT32
+from repro.core.dse_batch import AGGREGATE_OUTPUTS
 from repro.core.workloads import get_workload
-from repro.kernels.sweep_kernel import (CFG_FIELDS, LAY_FIELDS,
-                                        MIXED_CFG_FIELDS, _build_sweep_call,
-                                        _ceil_to, default_tiling)
+from repro.kernels.sweep_kernel import (MIXED_CFG_FIELDS, _build_packed_call,
+                                        _ceil_to, _packed_layout,
+                                        default_tiling)
 
 # name -> (configs per dispatch, workloads, per-layer precision columns)
 SHAPES = {
@@ -71,48 +74,46 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _operands(n_pad, l_pad, w, wide, sharding):
-    def spec(shape, int_fields, name):
-        dtype = jnp.int32 if name in int_fields else jnp.float32
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+def _compile_packed(n, bounds, wide, sharding):
+    """Compile the packed program for ``n`` configs over ``bounds``;
+    check its kernel and its six output columns."""
+    l, w = bounds[-1][1], len(bounds)
+    block_n, block_l = default_tiling(n, l)
+    n_pad, l_pad = _ceil_to(n, block_n), _ceil_to(l, block_l)
+    mixed_wide = (wide,) * len(MIXED_CFG_FIELDS)
+    _, size = _packed_layout(n_pad, l_pad, w, mixed_wide)
+    fn = _build_packed_call(n, n_pad, l_pad, bounds, block_n, block_l,
+                            mixed_wide, w == 1, False)
+    buf = jax.ShapeDtypeStruct((size,), jnp.int32, sharding=sharding)
+    compiled = fn.lower(buf).compile()
+    # the kernel's device op keeps the name profiles know it by
+    assert re.search(r"%tpu_custom_call(\.\d+)? = \S+ custom-call\(",
+                     compiled.as_text())
+    out = compiled.out_info
+    assert sorted(out) == sorted(AGGREGATE_OUTPUTS)
+    for col in out.values():
+        assert col.shape == ((n,) if w == 1 else (w, n))
+        assert np.dtype(col.dtype) == np.float32
+    return l_pad // block_l
 
-    ops = [spec((n_pad, l_pad if wide and name in MIXED_CFG_FIELDS else 1),
-                _CFG_INT32, name) for name in CFG_FIELDS]
-    ops += [spec((1, l_pad), _LAY_INT32, name) for name in LAY_FIELDS]
-    ops.append(jax.ShapeDtypeStruct((l_pad, w), jnp.float32,
-                                    sharding=sharding))    # segment mask
-    ops.append(jax.ShapeDtypeStruct((1, w), jnp.float32,
-                                    sharding=sharding))    # segment MACs
-    return ops
+
+def _bounds(workloads):
+    bounds, s = [], 0
+    for wl in workloads:
+        e = s + len(get_workload(wl).layers)
+        bounds.append((s, e))
+        s = e
+    return tuple(bounds)
 
 
 @pytest.mark.parametrize("name", sorted(SHAPES))
 def test_sweep_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     n, workloads, wide = SHAPES[name]
-    l = sum(len(get_workload(wl).layers) for wl in workloads)
-    w = len(workloads)
-    block_n, block_l = default_tiling(n, l)
-    n_pad, l_pad = _ceil_to(n, block_n), _ceil_to(l, block_l)
-    fn = _build_sweep_call(n_pad, l_pad, w, block_n, block_l,
-                           (wide,) * len(MIXED_CFG_FIELDS), False)
-    compiled = fn.lower(*_operands(n_pad, l_pad, w, wide,
-                                   one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    out, = jax.tree.leaves(compiled.out_info)
-    assert out.shape == (n_pad, 6 * w)
-    assert np.dtype(out.dtype) == np.float32
+    _compile_packed(n, _bounds(workloads), wide, one_chip)
 
 
 def test_sweep_kernel_compiles_for_v5e_over_three_layer_tiles(
         one_chip, no_persistent_cache):
     """A Moonlight-16B-A3B decode step (322 grouped GEMM rows) in a
     32,768-config stream chunk: three 128-wide layer tiles."""
-    n, l, w = 32768, 322, 1
-    block_n, block_l = default_tiling(n, l)
-    n_pad, l_pad = _ceil_to(n, block_n), _ceil_to(l, block_l)
-    assert l_pad // block_l == 3
-    fn = _build_sweep_call(n_pad, l_pad, w, block_n, block_l,
-                           (False,) * len(MIXED_CFG_FIELDS), False)
-    compiled = fn.lower(*_operands(n_pad, l_pad, w, False,
-                                   one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _compile_packed(32768, ((0, 322),), False, one_chip) == 3
